@@ -1,0 +1,14 @@
+"""Least time of the FC stages' passes (each the larger of its compute
+and HBM bound, from shapes) over their measured device time."""
+
+from chipbench import work
+
+
+def read(r):
+    if r.trace is None:
+        return None
+    stages = r.stages("fc")
+    ms = r.trace.stage_ms(stages)
+    if ms <= 0:
+        return None
+    return 100.0 * work.least_s(r.config, r.local_batch, r.peak, stages) * 1e3 / ms

@@ -1,0 +1,73 @@
+"""A whole run of a cell on the CPU at the small size, past the look for
+a chip: correct when the timed path is sound, not correct with each
+fault planted under it."""
+
+from __future__ import annotations
+
+import contextlib
+import pathlib
+import time
+
+import pytest
+
+from chipbench import cell as cells
+from chipbench import faults
+from chipbench.measure import measure
+
+ROOT = pathlib.Path(__file__).resolve().parents[3]
+INFO = {"platform": "cpu", "kind": "cpu", "count": 1}
+
+
+def run(name: str, config: dict, batch: int, fault=None, traced=False):
+    cell = cells.load(ROOT, name)
+    cell.config = config
+    cell.traffic = dict(cell.traffic, batch=batch)
+    peak = cells.peaks()["devices"]["TPU v5 lite"]
+    with fault() if fault else contextlib.nullcontext():
+        line, lines = measure(cell, 2**31 + 5, 0.3, traced,
+                              t0=time.perf_counter(), peak=peak,
+                              device_info=INFO)
+    return line, lines
+
+
+def test_sound_run_is_correct_and_reports_its_metrics(small_program,
+                                                      small_config):
+    line, lines = run("vgg11-b256", small_config, 8)
+    assert line["correct"], lines
+    assert line["attempted"] > 0 and line["failed"] == 0
+    assert set(line["metrics"]) == {"images_per_s", "step_ms_p95", "setup_s"}
+    assert list(line)[-1] == "compared"
+    assert line["setup"]["window_compiles"] == 0
+    assert [x.split()[0] for x in lines] == ["loss", "grad", "update"]
+
+
+def test_traced_run_off_the_chip_reads_no_layer(small_program, small_config):
+    # The CPU's trace has no TPU device plane: every reader finds nothing,
+    # and no share of a roofline or a peak is made up.
+    line, lines = run("vgg11-b256", small_config, 8, traced=True)
+    assert line["correct"], lines
+    assert line["metrics"] == {}
+    assert "breakdown" not in line
+
+
+@pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
+                                   "loss_altered"])
+def test_fault_is_not_correct(small_program, small_config, fault):
+    line, lines = run("vgg11-b256", small_config, 8, faults.FAULTS[fault])
+    assert not line["correct"], lines
+
+
+def test_no_tpu_exits_without_a_result():
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    out = subprocess.run(
+        [sys.executable, str(ROOT / "benchmarks/chip/run.py"), "--workload",
+         "vgg11-b256", "--seed", str(2**31 + 3), "--seconds", "1",
+         "--trace", "0"], cwd=ROOT, env=env, capture_output=True, text=True,
+        timeout=300)
+    assert out.returncode == 2
+    assert out.stdout.strip() == ""
+    assert "no TPU" in out.stderr
