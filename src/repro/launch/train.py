@@ -43,6 +43,7 @@ from repro.models.registry import (
     FAMILIES, batch_shard_specs, get_family, make_data_source,
 )
 from repro.optim import adamw
+from repro.runtime import spans
 from repro.runtime import train as tr
 from repro.runtime.chaos import ChaosConfig, ChaosMonkey
 from repro.runtime.fault_tolerance import (
@@ -205,24 +206,26 @@ def main(argv: Sequence[str] | None = None) -> LaunchResult:
         specs = param_specs(defs)
         pspecs = fsdp_specs(specs, aparams, ctx) if use_sharding else None
 
-        params = init_params(defs, jax.random.PRNGKey(tcfg.seed),
-                             jnp.dtype(tcfg.param_dtype))
-        state = tr.init_state(cfg, tcfg, params)
+        shardings = None
+        with spans.span("setup.init"):
+            params = init_params(defs, jax.random.PRNGKey(tcfg.seed),
+                                 jnp.dtype(tcfg.param_dtype))
+            state = tr.init_state(cfg, tcfg, params)
+            if use_sharding:
+                sstate = tr.TrainState(
+                    params=pspecs,
+                    opt=adamw.AdamWState(step=P(), m=pspecs, v=pspecs),
+                    err=None if state.err is None else pspecs)
+                shardings = jax.tree.map(
+                    lambda sp: NamedSharding(mesh, sp), sstate)
+                # Place the fresh state as the step expects it, so the
+                # first and later steps share one compiled program.
+                state = jax.device_put(state, shardings)
 
         # Resume from the newest *intact* committed step (corrupt steps
         # fall back with a logged warning — reshard-on-restore works even
         # if the mesh changed).
         start = 0
-        shardings = None
-        if use_sharding:
-            sstate = tr.TrainState(
-                params=pspecs,
-                opt=adamw.AdamWState(step=P(), m=pspecs, v=pspecs),
-                err=None if state.err is None else pspecs)
-            shardings = jax.tree.map(lambda sp: NamedSharding(mesh, sp), sstate)
-            # Place the fresh state as the step expects it, so the first
-            # and later steps share one compiled program.
-            state = jax.device_put(state, shardings)
         if args.ckpt:
             astate = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), state)

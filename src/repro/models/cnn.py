@@ -104,10 +104,13 @@ def make_loss_fn(cfg: ModelConfig, tcfg, parallel=None):
                           schedules=schedules)
         else:
             out = forward(cfg, params, imgs, use_kernels=False)
-        out = out.astype(jnp.float32)
-        lse = jax.nn.logsumexp(out, -1)
-        tgt = jnp.take_along_axis(out, labels[:, None], -1)[:, 0]
-        return (lse - tgt).mean()
+        # Under its own scope, as each stage is, so that the compiled
+        # step's loss operations carry it in op_name.
+        with jax.named_scope("loss"):
+            out = out.astype(jnp.float32)
+            lse = jax.nn.logsumexp(out, -1)
+            tgt = jnp.take_along_axis(out, labels[:, None], -1)[:, 0]
+            return (lse - tgt).mean()
 
     if not (tcfg.planned_kernels and parallel is not None
             and parallel.dp_size > 1):

@@ -66,8 +66,11 @@ def global_norm(tree) -> jax.Array:
     )
 
 
+@jax.named_scope("optimizer")
 def apply_updates(params, grads, state: AdamWState, cfg: TrainConfig):
-    """Returns (new_params, new_state, metrics)."""
+    """Returns (new_params, new_state, metrics).  The whole update, global
+    norm and clip included, runs under the ``optimizer`` name scope, so
+    that a compiled step's optimizer operations carry it in op_name."""
     gnorm = global_norm(grads)
     clip = jnp.minimum(1.0, cfg.grad_clip / (gnorm + 1e-9)) if cfg.grad_clip else 1.0
     step = state.step + 1

@@ -23,6 +23,7 @@ from repro.configs.base import ModelConfig, TrainConfig
 from repro.models.registry import get_family
 from repro.optim import adamw
 from repro.optim.compression import compress_tree, init_error_buffers
+from repro.runtime import spans
 
 
 @dataclasses.dataclass(frozen=True)
@@ -226,7 +227,9 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
     loss skips the update (the poisoned state is never committed) and
     after ``nonfinite_patience`` consecutive bad steps rolls back to the
     last good checkpoint.  Returns ``(final_state, history)`` where
-    history is one record per *executed* step."""
+    history is one record per *executed* step: its ``time`` is the
+    ``step`` span (:mod:`repro.runtime.spans`), and ``input_s``,
+    ``dispatch_s`` and ``wait_s`` are its three parts."""
     from repro.runtime.fault_tolerance import HostFailure
 
     policy = policy or RecoveryPolicy()
@@ -276,18 +279,24 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
 
     while step < steps:
         try:
-            t0 = time.time()
-            if chaos is not None:
-                death = chaos.host_death(step, run.n_devices)
-                if death is not None:
-                    raise HostFailure(dead=death[0], survivors=death[1])
-                chaos.on_step_start(step)  # straggle: counts into dt
-            batch = {k: jnp.asarray(v) for k, v in source(step).items()}
-            with (run.mesh if run.mesh is not None
-                  else contextlib.nullcontext()):
-                new_state, metrics = run.step_fn(run.state, batch)
-            loss = float(jax.block_until_ready(metrics["loss"]))
-            dt = time.time() - t0
+            with spans.span("step", step) as whole:
+                if chaos is not None:
+                    death = chaos.host_death(step, run.n_devices)
+                    if death is not None:
+                        raise HostFailure(dead=death[0], survivors=death[1])
+                    chaos.on_step_start(step)  # straggle: counts into dt
+                with spans.span("step.input", step) as fed:
+                    batch = {k: jnp.asarray(v)
+                             for k, v in source(step).items()}
+                with spans.span("step.dispatch", step) as sent, \
+                        (run.mesh if run.mesh is not None
+                         else contextlib.nullcontext()):
+                    new_state, metrics = run.step_fn(run.state, batch)
+                with spans.span("step.wait", step) as waited:
+                    loss = float(jax.block_until_ready(metrics["loss"]))
+            dt = whole.seconds
+            times = {"time": dt, "input_s": fed.seconds,
+                     "dispatch_s": sent.seconds, "wait_s": waited.seconds}
             if chaos is not None:
                 loss = chaos.poison_loss(step, loss)
 
@@ -323,7 +332,7 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
                 bad += 1
                 log(f"  [guard] step {step}: non-finite loss — update "
                     f"skipped ({bad}/{policy.nonfinite_patience})")
-                history.append({"step": step, "loss": loss, "time": dt,
+                history.append({"step": step, "loss": loss, **times,
                                 "skipped": True})
                 if bad >= policy.nonfinite_patience:
                     _recover(run.n_devices,
@@ -337,13 +346,16 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
             recoveries = 0  # the cap is on CONSECUTIVE recoveries:
             # a committed step in between proves real progress
             run.state = new_state  # committed only on a finite loss
-            history.append({"step": step, "loss": loss, "time": dt,
+            history.append({"step": step, "loss": loss, **times,
                             "skipped": False})
             if step % run.log_every == 0 or step == steps - 1:
                 extra = "".join(
                     f"  {k} {float(metrics[k]):.3g}"
                     for k in ("grad_norm", "lr") if k in metrics)
-                log(f"step {step:5d}  loss {loss:.4f}{extra}  {dt:.2f}s")
+                log(f"step {step:5d}  loss {loss:.4f}{extra}  {dt:.2f}s "
+                    f"(input {fed.seconds * 1e3:.1f} ms, dispatch "
+                    f"{sent.seconds * 1e3:.1f} ms, wait "
+                    f"{waited.seconds * 1e3:.1f} ms)")
             if (run.save is not None and run.ckpt_every
                     and step and step % run.ckpt_every == 0):
                 _commit(step, run.state)

@@ -235,3 +235,7 @@ def test_planned_train_step_compiles(topo, one_chip, no_cache, monkeypatch):
             sites.add((stage and stage.group(1), "transpose(" in name))
     want = {(s, bwd) for s in (*STAGES, "fc1", "fc2") for bwd in (False, True)}
     assert want <= sites, sorted(want - sites)
+    # The loss and the optimizer run under scopes of their own, which the
+    # stage pattern above does not take for a stage.
+    for scope in ("loss", "optimizer"):
+        assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', hlo), scope
