@@ -12,17 +12,26 @@ costs two clock reads and one check of the module's recorder.
 The span names, and what reads each:
 
 * ``step``          - one executed step of ``run_elastic``, from the
-                      chaos check to the loss read (its ``time`` record,
-                      the straggler watchdog, the log line);
-* ``step.input``    - the data source and ``jnp.asarray`` of the batch;
+                      chaos check to the loss read, the next step's
+                      input included (its ``time`` record, the straggler
+                      watchdog, the log line);
+* ``step.input``    - the data source and ``jnp.asarray`` of the batch.
+                      The loop brings each batch in while the previous
+                      step runs, so the span of step k's batch lies in
+                      step k-1's ``step`` span, between that step's
+                      dispatch and wait, and carries step number k; only
+                      the first step of a ``run_elastic`` call, or of a
+                      run rebuilt after a recovery, fetches in its own;
 * ``step.dispatch`` - the call of the jitted step; the first one holds
                       the trace, lowering and compile (or cache read);
 * ``step.wait``     - ``block_until_ready`` and the read of the loss;
 * ``setup.init``    - the launcher's parameter and optimizer-state
                       initialisation and their placement on the mesh.
 
-The three ``step.*`` durations are also in every history record
-(``input_s``, ``dispatch_s``, ``wait_s``) and in the loop's log line.
+The three ``step.*`` durations of step k are also in step k's history
+record (``input_s``, ``dispatch_s``, ``wait_s``, with ``prefetched``
+true where its input lies in step k-1's span) and in the loop's log
+line.
 
 The recorder belongs to the process, as the profiler does, and spans
 nest on the thread that runs the loop.

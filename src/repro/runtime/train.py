@@ -226,10 +226,28 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
     committed checkpoint — with bounded retries/backoff.  A non-finite
     loss skips the update (the poisoned state is never committed) and
     after ``nonfinite_patience`` consecutive bad steps rolls back to the
-    last good checkpoint.  Returns ``(final_state, history)`` where
-    history is one record per *executed* step: its ``time`` is the
-    ``step`` span (:mod:`repro.runtime.spans`), and ``input_s``,
-    ``dispatch_s`` and ``wait_s`` are its three parts."""
+    last good checkpoint.
+
+    The next batch is brought in while the current step runs: step N is
+    dispatched on a batch placed during step N-1, its new state is
+    committed provisionally, and only then is the source called for
+    step N+1 (so a source that reads ``run.state`` sees step N's
+    result) and its batch placed, before step N's loss is read.  A
+    non-finite loss or a host failure puts the previous state back; the
+    prefetched batch stays after a skipped update, since data does not
+    depend on state, and any recovery drops it (the rebuilt run fetches
+    its start afresh).  The source is never called for a step the call
+    does not run, and no state buffer is donated: the rollback needs the
+    previous state alive.
+
+    Returns ``(final_state, history)`` where history is one record per
+    *executed* step: its ``time`` is the ``step`` span
+    (:mod:`repro.runtime.spans`), which holds the step's dispatch and
+    wait and, but for the last step of a call, the next step's input;
+    ``input_s``, ``dispatch_s`` and ``wait_s`` are the spans of the
+    step's own batch, dispatch and wait, and ``prefetched`` says whether
+    that batch was brought in during the previous step (so its
+    ``input_s`` lies in the previous record's ``time``)."""
     from repro.runtime.fault_tolerance import HostFailure
 
     policy = policy or RecoveryPolicy()
@@ -240,6 +258,13 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
     history: list[dict] = []
     step = run.start
     pending = None  # in-flight async checkpoint write (ElasticRun.save)
+    ahead = None  # (step, batch, input seconds) brought in by the last step
+
+    def _fetch(at: int) -> tuple:
+        """Call the source for step ``at`` and place its batch."""
+        with spans.span("step.input", at) as fed:
+            batch = {k: jnp.asarray(v) for k, v in source(at).items()}
+        return at, batch, fed.seconds
 
     def _join_pending() -> None:
         """Wait for the in-flight checkpoint write.  This is THE join
@@ -259,7 +284,7 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
             pending = handle
 
     def _recover(survivors: int, why: str) -> None:
-        nonlocal run, recoveries, bad, slow, step
+        nonlocal run, recoveries, bad, slow, step, ahead
         # The last committed write must be on disk before build() restores
         # from it (and a broken writer must not be papered over by
         # restoring something older).
@@ -272,12 +297,14 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
             time.sleep(policy.backoff_seconds * 2 ** (recoveries - 1))
         log(f"[recover #{recoveries}] {why} -> rebuilding on "
             f"{survivors} device(s)")
+        ahead = None  # the rebuilt run fetches its start afresh
         run = build(survivors)
         bad = 0
         slow = 0
         step = run.start
 
     while step < steps:
+        prev = None  # the state before this step, while it is provisional
         try:
             with spans.span("step", step) as whole:
                 if chaos is not None:
@@ -285,18 +312,25 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
                     if death is not None:
                         raise HostFailure(dead=death[0], survivors=death[1])
                     chaos.on_step_start(step)  # straggle: counts into dt
-                with spans.span("step.input", step) as fed:
-                    batch = {k: jnp.asarray(v)
-                             for k, v in source(step).items()}
+                prefetched = ahead is not None and ahead[0] == step
+                _, batch, input_s = ahead if prefetched else _fetch(step)
+                ahead = None
                 with spans.span("step.dispatch", step) as sent, \
                         (run.mesh if run.mesh is not None
                          else contextlib.nullcontext()):
                     new_state, metrics = run.step_fn(run.state, batch)
+                # Provisional commit: the source for step + 1 sees this
+                # step's result.  A non-finite loss or a host failure
+                # puts ``prev`` back.
+                prev, run.state = run.state, new_state
+                if step + 1 < steps:
+                    ahead = _fetch(step + 1)  # overlaps this step's work
                 with spans.span("step.wait", step) as waited:
                     loss = float(jax.block_until_ready(metrics["loss"]))
             dt = whole.seconds
-            times = {"time": dt, "input_s": fed.seconds,
-                     "dispatch_s": sent.seconds, "wait_s": waited.seconds}
+            times = {"time": dt, "input_s": input_s,
+                     "dispatch_s": sent.seconds, "wait_s": waited.seconds,
+                     "prefetched": prefetched}
             if chaos is not None:
                 loss = chaos.poison_loss(step, loss)
 
@@ -329,6 +363,7 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
                 slow = 0
 
             if not math.isfinite(loss):
+                run.state = prev  # the update is skipped
                 bad += 1
                 log(f"  [guard] step {step}: non-finite loss — update "
                     f"skipped ({bad}/{policy.nonfinite_patience})")
@@ -345,7 +380,6 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
             bad = 0
             recoveries = 0  # the cap is on CONSECUTIVE recoveries:
             # a committed step in between proves real progress
-            run.state = new_state  # committed only on a finite loss
             history.append({"step": step, "loss": loss, **times,
                             "skipped": False})
             if step % run.log_every == 0 or step == steps - 1:
@@ -353,7 +387,8 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
                     f"  {k} {float(metrics[k]):.3g}"
                     for k in ("grad_norm", "lr") if k in metrics)
                 log(f"step {step:5d}  loss {loss:.4f}{extra}  {dt:.2f}s "
-                    f"(input {fed.seconds * 1e3:.1f} ms, dispatch "
+                    f"(input {input_s * 1e3:.1f} ms"
+                    f"{' prefetched' if prefetched else ''}, dispatch "
                     f"{sent.seconds * 1e3:.1f} ms, wait "
                     f"{waited.seconds * 1e3:.1f} ms)")
             if (run.save is not None and run.ckpt_every
@@ -368,6 +403,8 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
                         log(f"  [chaos] tore checkpoint chunk {torn}")
             step += 1
         except HostFailure as e:
+            if prev is not None:
+                run.state = prev
             _recover(e.survivors, f"host failure: dead={e.dead}")
 
     if run.save is not None:

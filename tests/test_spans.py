@@ -81,36 +81,53 @@ def test_run_elastic_records_one_step_span_per_executed_step():
     steps = [s for s in rec.spans if s.name == "step"]
     assert [s.step for s in steps] == [h["step"] for h in history] == [
         0, 1, 2, 3, 4]
+    assert [h["prefetched"] for h in history] == [False] + [True] * 4
+    parts = {(c.name, c.step): c for c in rec.spans if c.parent == "step"}
+    assert sorted(parts) == sorted((name, k) for name in PARTS
+                                   for k in range(5))
     for s, h in zip(steps, history):
-        parts = {c.name: c for c in rec.spans
-                 if c.parent == "step" and c.step == s.step}
-        assert sorted(parts) == sorted(PARTS)
-        for c in parts.values():
-            assert s.start_ns <= c.start_ns <= c.end_ns <= s.end_ns
-        assert (parts["step.input"].end_ns <= parts["step.dispatch"].start_ns
-                and parts["step.dispatch"].end_ns
-                <= parts["step.wait"].start_ns)
+        k = s.step
+        # A step's span holds, in order: its own input for the first
+        # step, its dispatch, the next step's input but for the last
+        # step, and its wait.
+        held = ([("step.input", k)] if k == 0 else []) + [
+            ("step.dispatch", k)] + (
+            [("step.input", k + 1)] if k < 4 else []) + [("step.wait", k)]
+        inside = sorted((c for c in parts.values()
+                         if s.start_ns <= c.start_ns <= s.end_ns),
+                        key=lambda c: c.start_ns)
+        assert [(c.name, c.step) for c in inside] == held
+        for a, b in zip(inside, inside[1:]):
+            assert a.end_ns <= b.start_ns
+        assert inside[-1].end_ns <= s.end_ns
         assert h["time"] == pytest.approx((s.end_ns - s.start_ns) * 1e-9,
                                           abs=1e-12)
         for name, key in zip(PARTS, ("input_s", "dispatch_s", "wait_s")):
-            c = parts[name]
+            c = parts[name, k]
             assert h[key] == pytest.approx((c.end_ns - c.start_ns) * 1e-9,
                                            abs=1e-12)
-        assert h["input_s"] + h["dispatch_s"] + h["wait_s"] <= h["time"]
+        assert sum((c.end_ns - c.start_ns) * 1e-9 for c in inside) <= h["time"]
     assert {s.name for s in rec.spans} == {"step", *PARTS}
     # The periodic log line says where each logged step went.
     lines = [x for x in logs if x.startswith("step")]
     assert len(lines) == 3  # steps 0, 2 and the last
     assert all("input" in x and "dispatch" in x and "wait" in x
                for x in lines)
+    assert ["prefetched" in x for x in lines] == [False, True, True]
 
 
 def test_run_elastic_without_a_recorder_keeps_its_step_records():
     _, history = tr.run_elastic(_toy_build, _toy_source, 3,
                                 log=lambda *_: None)
     assert [h["step"] for h in history] == [0, 1, 2]
-    for h in history:
-        assert h["time"] >= h["input_s"] + h["dispatch_s"] + h["wait_s"]
+    assert [h["prefetched"] for h in history] == [False, True, True]
+    for h, after in zip(history, history[1:] + [None]):
+        # Its dispatch and wait, its own input if it was not prefetched,
+        # and the next step's input all lie in a step's time.
+        held = h["dispatch_s"] + h["wait_s"]
+        held += 0.0 if h["prefetched"] else h["input_s"]
+        held += after["input_s"] if after is not None else 0.0
+        assert h["time"] >= held
         assert min(h["input_s"], h["dispatch_s"], h["wait_s"]) >= 0
 
 
