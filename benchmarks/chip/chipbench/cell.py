@@ -4,6 +4,8 @@ A cell (one entry of ``workloads``) names a configuration and a traffic
 mix.  Its pieces are files of their own beside this package:
 
 * the configuration: the ``file`` that ``BENCHMARK.json`` gives for it;
+* its model family:  ``families/<family>.py``, the configuration's
+                     ``family`` (see ``families/__init__.py``);
 * the traffic mix:   ``traffic/<traffic>.json``;
 * the limits of the comparison that decides ``correct``:
                      ``limits/<cell>.json``;
@@ -14,6 +16,7 @@ mix.  Its pieces are files of their own beside this package:
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import importlib.util
 import json
 import pathlib
@@ -47,11 +50,14 @@ def load(root: pathlib.Path, workload: str) -> Cell:
     config = json.loads((root / configs[w["config"]]["file"]).read_text())
     traffic = json.loads((HERE / "traffic" / f"{w['traffic']}.json").read_text())
     limits = json.loads((HERE / "limits" / f"{workload}.json").read_text())
+    end_to_end = [m for m in bench["end_to_end"] if _for(m, workload)]
+    # A per-layer metric belongs to the cells that report what it moves.
+    moved = {m["name"] for m in end_to_end}
     return Cell(
         name=workload, chips=int(w["chips"]), config=config, traffic=traffic,
-        limits=limits["limits"],
-        end_to_end=[m for m in bench["end_to_end"] if _for(m, workload)],
-        per_layer=[m for m in bench["per_layer"] if _for(m, workload)])
+        limits=limits["limits"], end_to_end=end_to_end,
+        per_layer=[m for m in bench["per_layer"]
+                   if m["moves"] in moved and _for(m, workload)])
 
 
 def reader(metric: str):
@@ -62,6 +68,16 @@ def reader(metric: str):
     module = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(module)
     return module.read
+
+
+def family(config: dict):
+    """The module ``families/<config["family"]>.py``."""
+    name = config["family"]
+    have = sorted(p.stem for p in (HERE / "families").glob("*.py")
+                  if p.stem != "__init__")
+    if name not in have:
+        raise SystemExit(f"no model family {name!r} in families/; have {have}")
+    return importlib.import_module(f"families.{name}")
 
 
 def peaks() -> dict:

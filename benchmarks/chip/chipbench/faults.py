@@ -2,7 +2,10 @@
 
 The tests see ``correct`` come out false under each; the calibration on
 the chip reads how far each moves the numbers compared.  A fault patches
-the program for the duration of the ``with`` and nothing else:
+the program for the duration of the ``with`` and nothing else.  The loss
+faults wrap ``repro.runtime.train.make_loss_fn``, through which the train
+step gets every family's loss, and cut every leaf of the batch on its
+leading axis, so that they hold for any family:
 
 * ``state_unchanged`` - the step returns the state it was given;
 * ``half_batch``      - the loss is the mean over the first half of the
@@ -20,6 +23,8 @@ import contextlib
 from unittest import mock
 
 import jax
+
+from chipbench.reference import rows
 
 
 @contextlib.contextmanager
@@ -62,28 +67,28 @@ def loss_altered():
 
 @contextlib.contextmanager
 def half_batch():
-    from repro.models import cnn
+    from repro.runtime import train as tr
 
-    original = cnn.make_loss_fn
+    original = tr.make_loss_fn
 
     def make(*args, **kwargs):
         loss_fn = original(*args, **kwargs)
 
         def half(params, batch):
-            n = batch["images"].shape[0] // 2
-            return loss_fn(params, {k: v[:n] for k, v in batch.items()})
+            n = rows(batch) // 2
+            return loss_fn(params, jax.tree.map(lambda v: v[:n], batch))
 
         return half
 
-    with mock.patch.object(cnn, "make_loss_fn", make):
+    with mock.patch.object(tr, "make_loss_fn", make):
         yield
 
 
 @contextlib.contextmanager
 def no_exchange():
-    from repro.models import cnn
+    from repro.runtime import train as tr
 
-    original = cnn.make_loss_fn
+    original = tr.make_loss_fn
 
     def make(cfg, tcfg, parallel=None):
         loss_fn = original(cfg, tcfg, parallel)
@@ -94,9 +99,9 @@ def no_exchange():
         def own(params, batch):
             full = loss_fn(params, batch)
             # Chip 0's share of the batch, through the same sharded path.
-            share = batch["images"].shape[0] // n
-            part = loss_fn(params, {k: jax.numpy.concatenate([v[:share]] * n)
-                                    for k, v in batch.items()})
+            share = rows(batch) // n
+            part = loss_fn(params, jax.tree.map(
+                lambda v: jax.numpy.concatenate([v[:share]] * n), batch))
             # Value: the mean over all chips; gradient: chip 0's own, from
             # the 1/n of the cotangent it gets.
             return (jax.lax.stop_gradient(full)
@@ -104,7 +109,7 @@ def no_exchange():
 
         return own
 
-    with mock.patch.object(cnn, "make_loss_fn", make):
+    with mock.patch.object(tr, "make_loss_fn", make):
         yield
 
 

@@ -119,7 +119,7 @@ def idle_by_host_span(chip_ops: dict, chip_modules: dict,
                     unaligned=unaligned)
 
 
-def rest_scopes(scopes: dict) -> dict:
+def rest_scopes(scopes: dict, stage_pattern: re.Pattern) -> dict:
     """``scopes`` (HLO instruction -> op_name) with the op_name of every
     operation under no stage but under the ``loss`` or ``optimizer``
     scope replaced by that scope's name.  ``trace.read_xplane`` then
@@ -127,8 +127,8 @@ def rest_scopes(scopes: dict) -> dict:
     and leaves every bucket, ``other`` included, as it was."""
     out = {}
     for hlo_op, op_name in scopes.items():
-        m = None if trace.classify(hlo_op, op_name).stage else _REST.search(
-            op_name)
+        staged = trace.classify(hlo_op, op_name, stage_pattern).stage
+        m = None if staged else _REST.search(op_name)
         out[hlo_op] = m.group(1) if m else op_name
     return out
 

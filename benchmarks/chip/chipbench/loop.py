@@ -25,7 +25,7 @@ from unittest import mock
 import jax
 import jax.numpy as jnp
 
-from chipbench.reference import Side
+from chipbench.reference import Side, norms
 
 REFERENCE_STEPS = 3
 BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
@@ -35,16 +35,13 @@ class WindowClosed(Exception):
     """Raised by the feed at the first step boundary after the window."""
 
 
-@jax.jit
-def _norms(tree):
-    return {k: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32))))
-            for k, v in tree.items()}
+_norms = jax.jit(norms)
 
 
 @jax.jit
 def _change_norms(new, old):
-    return _norms({k: new[k].astype(jnp.float32) - old[k].astype(jnp.float32)
-                   for k in new})
+    return norms(jax.tree.map(
+        lambda a, b: a.astype(jnp.float32) - b.astype(jnp.float32), new, old))
 
 
 class Capture:

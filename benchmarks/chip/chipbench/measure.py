@@ -12,12 +12,17 @@ import tempfile
 import warnings
 
 from chipbench import compare, loop, reference, trace, work
-from chipbench.cell import Cell, reader
+from chipbench.cell import Cell, family, reader
 from chipbench.inputs import BatchPool
 from chipbench.record import Record
 
 TRACE_SECONDS = 3.0  # a traced window is at most this long
 SEED_SPACE = 2**31  # the program's seed is the benchmark's seed modulo this
+# A layer whose fit gate drops it to the XLA path is not the planned step
+# this benchmark times: the run is refused.  The program's layers warn so
+# (``UnfitScheduleWarning``, under every family); the warning is matched
+# by its message, which names no layer.
+UNFIT = r"backward schedule .* overflows VMEM"
 
 
 def device_problem(devices, chips: int, peaks: dict) -> str | None:
@@ -40,24 +45,8 @@ def launcher_argv(config: dict, traffic: dict, seed: int) -> list:
             "--mesh", config["mesh"], "--batch", str(traffic["batch"]),
             "--steps", str(opt["total_steps"]),
             "--lr", repr(opt["learning_rate"]), "--seed", str(seed),
-            "--log-every", str(10**9), "--autotune", "off"]
-
-
-def check_program(config: dict) -> None:
-    """Refuse to run when the program's registry entry for the arch is
-    not the configuration the file states."""
-    from repro.launch import train as launcher
-    from repro.models import cnn
-
-    cfg = launcher.get_config(config["arch"])
-    have = {"conv_layers": cfg.n_layers, "first_channels": cfg.d_model,
-            "fc_hidden": cfg.d_ff, "classes": cfg.vocab,
-            "image_size": cnn.IMG, "in_channels": cnn.IN_CH, "filter": cnn.F,
-            "last_channels": cfg.d_model * 2 ** (cfg.n_layers - 1)}
-    wrong = {k: (v, config[k]) for k, v in have.items() if v != config[k]}
-    if wrong:
-        raise SystemExit(f"the program's {config['arch']} is not the "
-                         f"configuration's (program, file): {wrong}")
+            "--log-every", str(10**9), "--autotune", "off",
+            *family(config).launcher_args(config, traffic)]
 
 
 def _memory_peak(devices) -> int:
@@ -76,20 +65,20 @@ def step_hlo(result, mesh, batch: dict) -> str:
         return result.step_fn.lower(result.state, shapes).compile().as_text()
 
 
-def missing_kernels(hlo: str, stages) -> list:
+def missing_kernels(hlo: str, stages, stage_pattern) -> list:
     """Stages and directions of the step that hold no planned Mosaic
     kernel: each stage's forward and backward must hold one."""
     want = {(s, d) for s in stages for d in ("fwd", "bwd")}
-    return sorted(want - trace.kernel_sites(hlo))
+    return sorted(want - trace.kernel_sites(hlo, stage_pattern))
 
 
-def _reduce_trace(trace_dir: str, hlo: str):
+def _reduce_trace(trace_dir: str, hlo: str, stage_pattern):
     """The traced window's reduction, with the step's HLO for scopes."""
     files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
     if not files:
         return None
-    chip_ops, modules = trace.read_xplane(str(files[-1]),
-                                          trace.hlo_scopes(hlo))
+    chip_ops, modules = trace.read_xplane(
+        str(files[-1]), trace.hlo_scopes(hlo), stage_pattern)
     return trace.reduce(chip_ops, modules)
 
 
@@ -99,11 +88,10 @@ def measure(cell: Cell, seed: int, seconds: float, traced: bool, *,
     start.  Returns ``(result line, compared lines)``."""
     import jax
 
-    from repro.core.conv_layer import UnfitScheduleWarning
-
     config, traffic = cell.config, cell.traffic
+    fam = family(config)
     program_seed = seed % SEED_SPACE
-    check_program(config)
+    fam.check_program(config)
     pool = BatchPool(config, traffic, seed)
     capture = loop.Capture(config["optimizer"]["beta1"])
     window = min(seconds, TRACE_SECONDS) if traced else seconds
@@ -113,9 +101,7 @@ def measure(cell: Cell, seed: int, seconds: float, traced: bool, *,
     compiles = loop.CompileLog(feed)
     try:
         with compiles.listening(), warnings.catch_warnings():
-            # A stage whose fit gate drops it to the XLA path is not the
-            # planned step this benchmark times: the run is refused.
-            warnings.simplefilter("error", UnfitScheduleWarning)
+            warnings.filterwarnings("error", message=UNFIT)
             result, warm = loop.launch(
                 launcher_argv(config, traffic, program_seed), feed, trace_dir)
         intervals = feed.intervals
@@ -123,12 +109,13 @@ def measure(cell: Cell, seed: int, seconds: float, traced: bool, *,
         devices = jax.devices()[:cell.chips]
         memory = _memory_peak(devices)
         hlo = step_hlo(result, feed.run.mesh, pool[0])
-        stages = Record.stages_of(config, "conv") + Record.stages_of(config, "fc")
-        missing = missing_kernels(hlo, stages)
+        stages = [name for name, _ in fam.stages(config)]
+        missing = missing_kernels(hlo, stages, fam.STAGE_PATTERN)
         if missing and device_info["platform"] == "tpu":
             raise SystemExit(f"the compiled step holds no planned kernel for "
                              f"{missing}; nothing was measured")
-        reduction = _reduce_trace(trace_dir, hlo) if traced else None
+        reduction = (_reduce_trace(trace_dir, hlo, fam.STAGE_PATTERN)
+                     if traced else None)
     finally:
         if trace_dir is not None:
             shutil.rmtree(trace_dir, ignore_errors=True)
@@ -142,9 +129,9 @@ def measure(cell: Cell, seed: int, seconds: float, traced: bool, *,
     numbers = compare.readings(got, want)
     correct, lines = compare.judge(numbers, cell.limits)
 
-    record = Record(config=config, batch=pool.batch, chips=cell.chips,
-                    peak=peak, setup_s=setup_s, intervals=intervals,
-                    trace=reduction)
+    record = Record(config=config, traffic=traffic, batch=pool.batch,
+                    tokens=fam.tokens(pool[0]), chips=cell.chips, peak=peak,
+                    setup_s=setup_s, intervals=intervals, trace=reduction)
     metrics = {}
     for m in (cell.per_layer if traced else cell.end_to_end):
         value = reader(m["name"])(record)
@@ -158,9 +145,7 @@ def measure(cell: Cell, seed: int, seconds: float, traced: bool, *,
         device.update(busy_s=reduction.busy_s, window_s=reduction.window_s)
         line["breakdown"] = {"device_ops": reduction.top("label_ns"),
                              "idle_gaps": reduction.top("gap_ns")}
-        line["roofline_bounds"] = work.bounds(
-            config, record.local_batch, peak,
-            record.stages("conv") + record.stages("fc"))
+        line["roofline_bounds"] = work.bounds(record.passes, peak, stages)
     line["setup"] = {"to_first_step_s": feed.first - t0,
                      "warmup_s": feed.marks[0] - feed.first,
                      "cache_hits": compiles.hits,

@@ -4,13 +4,16 @@ from __future__ import annotations
 
 import dataclasses
 
+from chipbench import cell
 from chipbench.trace import Reduction
 
 
 @dataclasses.dataclass
 class Record:
     config: dict
-    batch: int  # images per step, over all chips
+    traffic: dict
+    batch: int  # examples per step, over all chips
+    tokens: int | None  # tokens per step, over all chips, or None
     chips: int
     peak: dict  # this device's row of peaks.json
     setup_s: float  # process start to the window's first step
@@ -25,12 +28,16 @@ class Record:
     def local_batch(self) -> int:
         return self.batch // self.chips
 
-    def stages(self, prefix: str) -> list:
-        """Stage names of one kind: ``conv`` or ``fc``."""
-        return self.stages_of(self.config, prefix)
+    @property
+    def family(self):
+        return cell.family(self.config)
 
-    @staticmethod
-    def stages_of(config: dict, prefix: str) -> list:
-        n = config["conv_layers"] if prefix == "conv" else 2
-        first = 0 if prefix == "conv" else 1
-        return [f"{prefix}{i}" for i in range(first, first + n)]
+    def stages(self, kind: str) -> list:
+        """Names of the family's stages of one kind."""
+        return [name for name, k in self.family.stages(self.config)
+                if k == kind]
+
+    @property
+    def passes(self) -> list:
+        """Every pass of one step on one chip's share of the batch."""
+        return self.family.passes(self.config, self.traffic, self.local_batch)

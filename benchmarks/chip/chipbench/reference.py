@@ -1,39 +1,35 @@
-"""A plain float32 CNN training step: the reference of every comparison.
+"""A plain float32 training step: the reference of every comparison.
 
-It follows the configuration file alone and imports nothing of the
-program: 3x3 same-padded convolutions (``lax.conv_general_dilated``) with
-bias, ReLU and 2x2 max-pool, two dense layers, mean cross-entropy, and
-AdamW with global-norm clipping, linear warm-up and cosine decay, weight
-decay on matrices only.  Weights are drawn from the seed with the
-initialisation the configuration states (``normal / sqrt(fan_in)`` per
-leaf, the leaf's key folded from the CRC-32 of its name; biases zero).
+The model is the family's (``families/<family>.py``: ``init_params`` and
+``loss_fn``), which follows the configuration file alone and imports
+nothing of the program.  The step around it is the same for every
+family: AdamW with global-norm clipping, linear warm-up and cosine decay,
+weight decay on leaves of two or more dimensions.
 
-The step's gradient is summed over blocks of at most ``BLOCK_ROWS``
-images, so that the reference fits beside nothing else on one chip at
-the batch the window trains on; the loss is the mean over all of them.
+The step's gradient is summed over blocks of at most the family's
+``BLOCK_ROWS`` examples, so that the reference fits beside nothing else
+on one chip at the batch the window trains on; the loss is the mean over
+all of them, which holds as long as every example weighs the same in the
+family's loss.
 
-``precision="fp8"`` is the control: every convolution and matmul operand,
-and every gradient flowing back out of one, is rounded to float8 e4m3
-with a per-tensor scale, the step below the bfloat16 that the
+``precision="fp8"`` is the control: every operand of a convolution or a
+matrix product, and every gradient flowing back out of one, is rounded to
+float8 e4m3 with a per-tensor scale, the step below the bfloat16 that the
 configuration computes in.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import math
-import zlib
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from chipbench.work import stage_shapes
+from chipbench import cell
 
-HIGHEST = lax.Precision.HIGHEST
 F8 = jnp.float8_e4m3fn
 F8_MAX = 448.0
-BLOCK_ROWS = 1024  # images of one block of the reference's gradient
 
 
 @dataclasses.dataclass
@@ -44,18 +40,6 @@ class Side:
     grad: dict  # leaf -> norm of the first step's gradient after clipping
     update: dict  # leaf -> norm of the parameters' change after the steps
     grad_raw: dict = None  # leaf -> norm of the first unclipped gradient
-
-
-def init_params(config: dict, seed: int) -> dict:
-    key = jax.random.PRNGKey(seed)
-    params = {}
-    for name, _, w, _ in stage_shapes(config, 1):
-        fan_in = w[2] if name.startswith("conv") else w[0]
-        k = jax.random.fold_in(key, zlib.crc32(name.encode()) & 0x7FFFFFFF)
-        params[name] = jax.random.normal(k, w, jnp.float32) * (1.0 / math.sqrt(fan_in))
-        bias = f"bias{name[4:]}" if name.startswith("conv") else f"{name}_b"
-        params[bias] = jnp.zeros((w[-1],), jnp.float32)
-    return params
 
 
 @jax.custom_vjp
@@ -71,31 +55,6 @@ def _round_fp8(x):
 _fp8.defvjp(lambda x: (_round_fp8(x), None), lambda _, g: (_round_fp8(g),))
 
 
-def logits(params, images, config: dict, precision: str = "float32"):
-    q = _fp8 if precision == "fp8" else (lambda a: a)
-    pool = config["pool"]
-    x = images
-    for i in range(config["conv_layers"]):
-        w = params[f"conv{i}"]
-        pad = w.shape[0] // 2
-        x = lax.conv_general_dilated(
-            q(x), q(w), (1, 1), [(pad, pad), (pad, pad)],
-            dimension_numbers=("NHWC", "HWIO", "NHWC"), precision=HIGHEST)
-        x = jnp.maximum(x + params[f"bias{i}"], 0.0)
-        x = lax.reduce_window(x, -jnp.inf, lax.max, (1, pool, pool, 1),
-                              (1, pool, pool, 1), "VALID")
-    x = x.reshape(x.shape[0], -1)
-    x = jnp.dot(q(x), q(params["fc1"]), precision=HIGHEST) + params["fc1_b"]
-    x = jnp.maximum(x, 0.0)
-    return jnp.dot(q(x), q(params["fc2"]), precision=HIGHEST) + params["fc2_b"]
-
-
-def loss_fn(params, images, labels, config: dict, precision: str):
-    x = logits(params, images, config, precision)
-    lse = jax.nn.logsumexp(x, -1)
-    return jnp.mean(lse - jnp.take_along_axis(x, labels[:, None], -1)[:, 0])
-
-
 def lr_at(opt: dict, step):
     """Learning rate of update ``step`` (1-based)."""
     step = step.astype(jnp.float32)
@@ -105,63 +64,84 @@ def lr_at(opt: dict, step):
     return opt["learning_rate"] * warm * (0.1 + 0.45 * (1 + jnp.cos(jnp.pi * frac)))
 
 
+def leaves(tree) -> dict:
+    """Every leaf of a tree by its ``/``-joined path (``layers/attn/wq``;
+    a flat dict's leaves keep their keys)."""
+    flat, _ = jax.tree_util.tree_flatten_with_path(tree)
+    return {jax.tree_util.keystr(path, simple=True, separator="/"): leaf
+            for path, leaf in flat}
+
+
 def norms(tree) -> dict:
     return {k: jnp.sqrt(jnp.sum(jnp.square(v.astype(jnp.float32))))
-            for k, v in tree.items()}
+            for k, v in leaves(tree).items()}
 
 
-def blocks(batch: int) -> int:
-    """How many equal blocks of at most ``BLOCK_ROWS`` images a batch is
+def blocks(batch: int, block_rows: int) -> int:
+    """How many equal blocks of at most ``block_rows`` examples a batch is
     summed over."""
-    n = -(-batch // BLOCK_ROWS)
+    n = -(-batch // block_rows)
     if batch % n:
-        raise ValueError(f"a batch of {batch} images does not split into "
-                         f"{n} equal blocks of at most {BLOCK_ROWS}")
+        raise ValueError(f"a batch of {batch} examples does not split into "
+                         f"{n} equal blocks of at most {block_rows}")
     return n
 
 
-def make_step(config: dict, precision: str):
-    """One jitted reference step: ``(params, m, v, t, images, labels) ->
-    (params, m, v, t, loss, clipped gradient norms, raw gradient norms)``."""
-    opt = config["optimizer"]
+def rows(batch) -> int:
+    """Examples in a batch: the leading axis of its leaves."""
+    return jax.tree.leaves(batch)[0].shape[0]
 
-    def block_sum(params, images, labels):
+
+def make_step(config: dict, precision: str):
+    """One jitted reference step: ``(params, m, v, t, batch) ->
+    (params, m, v, t, loss, clipped gradient norms, raw gradient norms)``."""
+    family = cell.family(config)
+    opt = config["optimizer"]
+    q = _fp8 if precision == "fp8" else (lambda a: a)
+
+    def block_sum(params, block):
         # Sum, not mean, over the block: the blocks add up to the batch.
-        return loss_fn(params, images, labels, config, precision) * len(labels)
+        return family.loss_fn(params, block, config, q) * rows(block)
 
     @jax.jit
-    def step(params, m, v, t, images, labels):
-        n = blocks(len(labels))
+    def step(params, m, v, t, batch):
+        n_rows = rows(batch)
+        n = blocks(n_rows, family.BLOCK_ROWS)
 
         def add(acc, block):
-            loss, grads = jax.value_and_grad(block_sum)(params, *block)
+            loss, grads = jax.value_and_grad(block_sum)(params, block)
             return jax.tree.map(jnp.add, acc, (loss, grads)), None
 
         zero = (jnp.zeros((), jnp.float32),
                 jax.tree.map(jnp.zeros_like, params))
         (loss, grads), _ = lax.scan(
-            add, zero, (images.reshape(n, -1, *images.shape[1:]),
-                        labels.reshape(n, -1)))
-        loss = loss / len(labels)
-        grads = jax.tree.map(lambda g: g / len(labels), grads)
-        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g)) for g in grads.values()))
+            add, zero, jax.tree.map(lambda a: a.reshape(n, -1, *a.shape[1:]),
+                                    batch))
+        loss = loss / n_rows
+        grads = jax.tree.map(lambda g: g / n_rows, grads)
+        gnorm = jnp.sqrt(sum(jnp.sum(jnp.square(g))
+                             for g in jax.tree.leaves(grads)))
         clip = jnp.minimum(1.0, opt["grad_clip"] / (gnorm + 1e-9))
         t = t + 1
         lr = lr_at(opt, t)
         b1, b2 = opt["beta1"], opt["beta2"]
         bc1 = 1 - b1 ** t.astype(jnp.float32)
         bc2 = 1 - b2 ** t.astype(jnp.float32)
-        new_p, new_m, new_v, clipped = {}, {}, {}, {}
-        for k, p in params.items():
-            g = grads[k] * clip
-            clipped[k] = g
-            new_m[k] = b1 * m[k] + (1 - b1) * g
-            new_v[k] = b2 * v[k] + (1 - b2) * g * g
-            u = (new_m[k] / bc1) / (jnp.sqrt(new_v[k] / bc2) + opt["eps"])
+
+        def adamw(p, g, m, v):
+            g = g * clip
+            m = b1 * m + (1 - b1) * g
+            v = b2 * v + (1 - b2) * g * g
+            u = (m / bc1) / (jnp.sqrt(v / bc2) + opt["eps"])
             if p.ndim >= 2:
                 u = u + opt["weight_decay"] * p
-            new_p[k] = p - lr * u
-        return new_p, new_m, new_v, t, loss, norms(clipped), norms(grads)
+            return p - lr * u, m, v, g
+
+        out = jax.tree.map(adamw, params, grads, m, v)
+        part = lambda i: jax.tree.map(lambda o: o[i], out,
+                                      is_leaf=lambda o: isinstance(o, tuple))
+        return (part(0), part(1), part(2), t, loss, norms(part(3)),
+                norms(grads))
 
     return step
 
@@ -173,19 +153,18 @@ def train(config: dict, batches, seed: int, precision: str = "float32",
     device = device or jax.devices()[0]
     step = make_step(config, precision)
     with jax.default_device(device), jax.default_matmul_precision("highest"):
-        params = p0 = init_params(config, seed)
-        m = {k: jnp.zeros_like(p) for k, p in params.items()}
-        v = dict(m)
+        params = p0 = cell.family(config).init_params(config, seed)
+        m = jax.tree.map(jnp.zeros_like, params)
+        v = m
         t = jnp.zeros((), jnp.int32)
         losses, grad, grad_raw = [], None, None
         for b in batches:
             params, m, v, t, loss, gclip, graw = step(
-                params, m, v, t, jnp.asarray(b["images"]),
-                jnp.asarray(b["labels"]))
+                params, m, v, t, {k: jnp.asarray(a) for k, a in b.items()})
             losses.append(loss)
             if grad is None:
                 grad, grad_raw = gclip, graw
-        update = norms({k: params[k] - p0[k] for k in params})
+        update = norms(jax.tree.map(jnp.subtract, params, p0))
     get = lambda d: {k: float(x) for k, x in d.items()}
     return Side([float(x) for x in losses], get(grad), get(update),
                 get(grad_raw))

@@ -2,12 +2,12 @@
 
 Device operations come from each chip's ``XLA Ops`` line.  Each is tied
 to the compiled step's HLO instruction of the same name, whose ``op_name``
-metadata carries the model's ``jax.named_scope`` stage (``conv0`` ..
-``fc2``) and, under ``transpose(``, the backward pass.  Collectives are
-kept apart from the stages.  The window of a chip runs from the start of
-the first to the start of the last execution of the step's program on
-its ``XLA Modules`` line (the program executed most often), so its steps
-are the executions between.  An idle gap lies either inside a step's
+metadata carries the model's ``jax.named_scope`` stage (found by the
+family's ``STAGE_PATTERN``) and, under ``transpose(``, the backward pass.
+Collectives are kept apart from the stages.  The window of a chip runs
+from the start of the first to the start of the last execution of the
+step's program on its ``XLA Modules`` line (the program executed most
+often), so its steps are the executions between.  An idle gap lies either inside a step's
 program or between two of them, where the chip waits on the host loop.
 
 The trace is taken with host tracing off: with it on, the host records
@@ -23,7 +23,6 @@ import re
 
 BETWEEN = "between steps (host loop: batch transfer, dispatch, loss read)"
 INSIDE = "inside a step"
-_STAGE = re.compile(r"(?:^|[/(])((?:conv|fc)\d+)(?:[/)]|$)")
 _KERNEL = re.compile(r"jit\((\w+)\)/pallas_call")
 _COLLECTIVE = re.compile(
     r"all-reduce|all-gather|reduce-scatter|collective-permute|all-to-all")
@@ -40,7 +39,7 @@ class Op:
     start_ns: float
     end_ns: float
     label: str  # "<stage>.<fwd|bwd>:<kernel or op>", for the breakdown
-    stage: str | None  # conv0.. / fc1 / fc2, or None
+    stage: str | None  # the stage's scope, or None
     collective: bool
 
 
@@ -206,23 +205,23 @@ def hlo_scopes(hlo_text: str) -> dict:
     return out
 
 
-def kernel_sites(hlo_text: str) -> set:
+def kernel_sites(hlo_text: str, stage_pattern: re.Pattern) -> set:
     """``(stage, "fwd" | "bwd")`` of every Mosaic kernel
     (``tpu_custom_call``) of a compiled module that lies under a stage."""
     sites = set()
     for line in hlo_text.splitlines():
         m = _HLO_LINE.match(line)
         if m and 'custom_call_target="tpu_custom_call"' in line:
-            stage = _STAGE.search(m.group(2))
+            stage = stage_pattern.search(m.group(2))
             if stage:
                 sites.add((stage.group(1),
                            "bwd" if "transpose(" in m.group(2) else "fwd"))
     return sites
 
 
-def classify(hlo_op: str, op_name: str) -> Op:
+def classify(hlo_op: str, op_name: str, stage_pattern: re.Pattern) -> Op:
     """Stage, direction and label of one operation (interval left 0)."""
-    stage = _STAGE.search(op_name)
+    stage = stage_pattern.search(op_name)
     stage = stage.group(1) if stage else None
     kernel = _KERNEL.search(op_name)
     if kernel:
@@ -244,10 +243,10 @@ def instruction(event_name: str) -> str:
     return m.group(1) if m else event_name
 
 
-def read_xplane(path: str, scopes: dict):
+def read_xplane(path: str, scopes: dict, stage_pattern: re.Pattern):
     """Device operations and program executions by chip from an
     ``.xplane.pb``.  ``scopes`` maps HLO instruction names to their
-    ``op_name``."""
+    ``op_name``; ``stage_pattern`` finds a stage in one."""
     from jax.profiler import ProfileData
 
     chip_ops: dict = collections.defaultdict(list)
@@ -270,7 +269,7 @@ def read_xplane(path: str, scopes: dict):
                     if k is None:
                         hlo_op = instruction(ev.name)
                         k = kinds[ev.name] = classify(
-                            hlo_op, scopes.get(hlo_op, ""))
+                            hlo_op, scopes.get(hlo_op, ""), stage_pattern)
                     chip_ops[chip].append(dataclasses.replace(
                         k, start_ns=ev.start_ns,
                         end_ns=ev.start_ns + ev.duration_ns))

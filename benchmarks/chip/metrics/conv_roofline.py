@@ -11,4 +11,4 @@ def read(r):
     ms = r.trace.stage_ms(stages)
     if ms <= 0:
         return None
-    return 100.0 * work.least_s(r.config, r.local_batch, r.peak, stages) * 1e3 / ms
+    return 100.0 * work.least_s(r.passes, r.peak, stages) * 1e3 / ms

@@ -5,9 +5,11 @@
         --seconds 10 --trace 0
 
 The cell's configuration, traffic, limits and metric readers are found by
-the names in ``BENCHMARK.json`` (see ``chipbench/cell.py``).  The run
-trains through the program's own launcher and loop, times the window,
-checks the first steps against a plain float32 reference, and prints one
+the names in ``BENCHMARK.json``, and its model family (inputs, reference,
+stages and work counts: ``families/<family>.py``) by the configuration's
+``family`` (see ``chipbench/cell.py``).  The run trains through the
+program's own launcher and loop, times the window, checks the first steps
+against the family's plain float32 reference, and prints one
 JSON object as the last line of standard output:
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` (and with
 ``--trace 1`` a ``breakdown``), with the numbers compared last.  With

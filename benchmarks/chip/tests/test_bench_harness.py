@@ -50,6 +50,21 @@ def test_traced_run_off_the_chip_reads_no_layer(small_program, small_config):
     assert "breakdown" not in line
 
 
+# The numbers compared in the sound small run, as the harness read them
+# before the CNN's pieces moved into families/cnn.py (parent commit of
+# that move, this seed and size, on the CPU): the move changed no
+# arithmetic of either side.
+PINNED = {"loss": "0x1.b6b02eb652db5p-24", "grad": "0x1.44a9b44180e68p-21",
+          "update": "0x1.cf81b1e30428ep-24"}
+
+
+def test_sound_run_compares_the_numbers_pinned_before_the_family_move(
+        small_program, small_config):
+    line, lines = run("vgg11-b256", small_config, 8)
+    got = {k: v["value"].hex() for k, v in line["compared"].items()}
+    assert got == PINNED, lines
+
+
 @pytest.mark.parametrize("fault", ["state_unchanged", "half_batch",
                                    "loss_altered"])
 def test_fault_is_not_correct(small_program, small_config, fault):

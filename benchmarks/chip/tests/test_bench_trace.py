@@ -5,7 +5,8 @@ from __future__ import annotations
 import pytest
 
 from chipbench import trace
-from chipbench.trace import Module, Op, classify
+from chipbench.trace import Module, Op
+from families.cnn import STAGE_PATTERN
 
 
 def op(start, end, stage=None, collective=False, label=None):
@@ -113,7 +114,7 @@ def test_no_window_or_no_device_op_reads_nothing():
     ("copy.2", "", None, "other.fwd:copy"),
 ])
 def test_classify(hlo_op, op_name, stage, label):
-    c = classify(hlo_op, op_name)
+    c = trace.classify(hlo_op, op_name, STAGE_PATTERN)
     assert (c.stage, c.label) == (stage, label)
     assert c.collective == hlo_op.startswith("all-reduce")
 
@@ -145,6 +146,7 @@ def test_missing_kernels():
             # fc1's backward runs on XLA: a dot, no Mosaic kernel.
             + '  %dot.3 = f32[4]{0} dot(), '
               'metadata={op_name="jit(step)/transpose(jvp(fc1))/dot_general"}\n')
-    assert trace.kernel_sites(text) == {("conv0", "fwd"), ("conv0", "bwd"),
-                                        ("fc1", "fwd")}
-    assert missing_kernels(text, ["conv0", "fc1"]) == [("fc1", "bwd")]
+    assert trace.kernel_sites(text, STAGE_PATTERN) == {
+        ("conv0", "fwd"), ("conv0", "bwd"), ("fc1", "fwd")}
+    assert missing_kernels(text, ["conv0", "fc1"], STAGE_PATTERN) == [
+        ("fc1", "bwd")]

@@ -12,7 +12,8 @@ nothing about ``correct``.  Prints one JSON object as the last line of
 standard output: the host spans' means over the window's steps
 (``host_input_ms``, ``host_dispatch_ms``, ``host_wait_ms``), the set-up
 spans (``setup_init_s``: ``setup.init``; ``setup_compile_s``: the first
-step's ``step.dispatch``), the rest of the step by scope
+step's ``step.dispatch``), the device time of each kind of the family's
+stages (``<kind>_device_ms``), the rest of the step by scope
 (``loss_device_ms``, ``optim_device_ms``, beside ``other_device_ms``),
 the window's idle time by host span (``idle_by_host_span``,
 ``idle_in_input_ms``, with the share of executions outside every
@@ -48,10 +49,10 @@ def traced(cell, seed: int, seconds: float, host_level: int) -> dict:
 
     from chipbench import hostspans, loop, measure, trace
     from chipbench.inputs import BatchPool
-    from chipbench.record import Record
     from repro.runtime import spans
 
     config, traffic = cell.config, cell.traffic
+    family = cells.family(config)
     start_trace = jax.profiler.start_trace
 
     # ``loop.launch`` starts the profiler with the host tracer off; the
@@ -74,7 +75,9 @@ def traced(cell, seed: int, seconds: float, host_level: int) -> dict:
         files = sorted(pathlib.Path(trace_dir).rglob("*.xplane.pb"))
         path = str(files[-1])
         chip_ops, modules = trace.read_xplane(
-            path, hostspans.rest_scopes(trace.hlo_scopes(hlo)))
+            path, hostspans.rest_scopes(trace.hlo_scopes(hlo),
+                                        family.STAGE_PATTERN),
+            family.STAGE_PATTERN)
         reduction = trace.reduce(chip_ops, modules)
         idle = hostspans.idle_by_host_span(chip_ops, modules,
                                            hostspans.read_host_spans(path))
@@ -95,13 +98,14 @@ def traced(cell, seed: int, seconds: float, host_level: int) -> dict:
         "setup_s": feed.marks[0] - T0,
     }
     if reduction is not None:
+        kinds = {}
+        for name, kind in family.stages(config):
+            kinds.setdefault(kind, []).append(name)
         line.update(
             device_idle_share=100.0 * (1 - reduction.busy_s
                                        / reduction.window_s),
-            conv_device_ms=reduction.stage_ms(
-                Record.stages_of(config, "conv")),
-            fc_device_ms=reduction.stage_ms(
-                Record.stages_of(config, "fc")),
+            **{f"{kind}_device_ms": reduction.stage_ms(names)
+               for kind, names in kinds.items()},
             other_device_ms=reduction.stage_ms(["other"]),
             loss_device_ms=hostspans.rest_ms(reduction, "loss"),
             optim_device_ms=hostspans.rest_ms(reduction, "optimizer"),
