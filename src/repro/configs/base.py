@@ -8,7 +8,7 @@ import dataclasses
 @dataclasses.dataclass(frozen=True)
 class ModelConfig:
     name: str
-    family: str  # dense | moe | rwkv6 | zamba2 | encdec | cnn
+    family: str  # dense | moe | lfm2 | rwkv6 | zamba2 | encdec | cnn
     n_layers: int
     d_model: int
     vocab: int
@@ -31,11 +31,19 @@ class ModelConfig:
     n_experts: int = 0
     moe_top_k: int = 0
     capacity_factor: float = 1.25
+    d_ff_expert: int = 0  # routed experts' width, where it differs from d_ff
+    # The routed experts this chip holds, (first, count); None holds all.
+    # The router still scores every one of n_experts.
+    experts_held: tuple[int, int] | None = None
+    # Hybrid stacks (lfm2): each layer's mixer ("conv" | "full_attention"),
+    # and how many leading layers have a dense FFN in place of experts.
+    layer_types: tuple[str, ...] = ()
+    n_dense_layers: int = 0
     # SSM / hybrid
     ssm_state: int = 0
     ssm_head_dim: int = 64
     ssm_expand: int = 2
-    conv_width: int = 4
+    conv_width: int = 4  # causal depthwise conv taps (SSM; lfm2's short conv)
     shared_attn_every: int = 0  # zamba2: shared attn block cadence
     # encoder-decoder
     n_enc_layers: int = 0
@@ -45,6 +53,11 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or (self.d_model // max(self.n_heads, 1))
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return self.experts_held or (0, self.n_experts)
 
     @property
     def attention_free(self) -> bool:

@@ -19,10 +19,13 @@ ARCH_IDS = [
     "rwkv6-1.6b",
     "seamless-m4t-medium",
     "zamba2-1.2b",
+    "lfm2-8b-a1b",
+    "lfm2-8b-a1b-ep4",  # one chip's share of a four-chip expert-parallel host
     "cnn-vgg11",  # the paper's own domain
 ]
 
 _MODULE_FOR = {a: a.replace("-", "_").replace(".", "p") for a in ARCH_IDS}
+_MODULE_FOR["lfm2-8b-a1b-ep4"] = _MODULE_FOR["lfm2-8b-a1b"]
 
 # The reference arch per model family: what ``launch/train.py --family X``
 # trains when no --arch is named (always as the reduced smoke config).
@@ -30,6 +33,7 @@ FAMILY_DEFAULT_ARCH = {
     "dense": "qwen1.5-0.5b",
     "transformer": "qwen1.5-0.5b",  # the planned wing's family name
     "moe": "qwen3-moe-235b-a22b",
+    "lfm2": "lfm2-8b-a1b",
     "rwkv6": "rwkv6-1.6b",
     "zamba2": "zamba2-1.2b",
     "encdec": "seamless-m4t-medium",
@@ -41,7 +45,9 @@ def get_config(arch: str) -> ModelConfig:
     if arch not in _MODULE_FOR:
         raise ValueError(f"unknown arch {arch!r}; have {ARCH_IDS}")
     mod = importlib.import_module(f"repro.configs.{_MODULE_FOR[arch]}")
-    return mod.CONFIG
+    # A module may hold a published configuration and shares derived from
+    # it (``CONFIGS``, keyed by arch id).
+    return getattr(mod, "CONFIGS", {}).get(arch, mod.CONFIG)
 
 
 def smoke_config(arch: str) -> ModelConfig:
@@ -68,6 +74,11 @@ def smoke_config(arch: str) -> ModelConfig:
         changes.update(ssm_head_dim=32)
     if cfg.family == "cnn":
         changes.update(n_layers=2, d_model=8, d_ff=64, vocab=10)
+    if cfg.family == "lfm2":
+        n = changes["n_layers"]
+        changes.update(layer_types=cfg.layer_types[:n], d_ff_expert=64,
+                       n_experts=8, n_dense_layers=min(cfg.n_dense_layers, 1),
+                       experts_held=(2, 4) if cfg.experts_held else None)
     if cfg.local_window:
         changes.update(local_window=64, global_every=cfg.global_every)
     return dataclasses.replace(cfg, name=cfg.name + "-smoke", **changes)

@@ -86,7 +86,15 @@ TPU_V5E = MachineModel(
     charge_stream_blocks=True,  # Pallas double-buffers whole blocks in VMEM
 )
 
-MACHINES = {m.name: m for m in (MANTICORE, TPU_V5E)}
+# The same chip as seen by a kernel that passes the compiler no VMEM limit
+# (the grouped matmul of jax's megablox): it compiles under the compiler's
+# default scoped limit, 16 MiB on v5e, and its planner counts every block
+# of it, double buffers included, so no stream reservation is set apart.
+TPU_V5E_SCOPED = dataclasses.replace(
+    TPU_V5E, name="tpu_v5e_scoped", local_mem_bytes=16 * MIB,
+    dma_buffer_bytes=0)
+
+MACHINES = {m.name: m for m in (MANTICORE, TPU_V5E, TPU_V5E_SCOPED)}
 
 
 def machine_named(name: str, default: MachineModel = TPU_V5E) -> MachineModel:

@@ -269,9 +269,9 @@ def main(argv: Sequence[str] | None = None) -> LaunchResult:
             # family branching in the launcher.
             bspec = {k: NamedSharding(mesh, s)
                      for k, s in batch_shard_specs(cfg, dp).items()}
-            step_fn = jax.jit(step_fn, in_shardings=(shardings, bspec))
+            step_fn = tr.DonatingStep(step_fn, in_shardings=(shardings, bspec))
         else:
-            step_fn = jax.jit(step_fn)
+            step_fn = tr.DonatingStep(step_fn)
 
         hb = mon = save = None
         if args.ckpt:

@@ -1,4 +1,9 @@
-"""Mixture-of-Experts transformer (grok-1, qwen3-moe).
+"""Mixture-of-Experts transformer (grok-1, qwen3-moe), and the held-expert
+FFN of the planned path (:func:`held_expert_ffn`, used by models/lfm2.py).
+
+Two expert paths live here.  The XLA family below drops every assignment
+past its capacity factor; :func:`held_expert_ffn` holds a chip's share of
+the experts and drops none, through the planned grouped matmul.
 
 Expert dispatch follows the paper's Alg 4 structure (DESIGN.md Sec. 4):
 every TP device keeps a *private partial output* for the experts it owns
@@ -146,6 +151,126 @@ def apply_moe_ffn(mp, x, cfg: ModelConfig, parallel=None):
         out_specs=P(dp, None, None),
         check_vma=False,
     )(x, mp["router"], mp["w_gate"], mp["w_up"], mp["w_down"])
+
+
+# ---------------------------------------------------------------------------
+# Held experts with no capacity (the planned path; models/lfm2.py)
+# ---------------------------------------------------------------------------
+
+
+def route(x, router, cfg: ModelConfig):
+    """``x`` [T, d] -> (expert ids [T, k] int32, gates [T, k] f32): the
+    top k of ``sigmoid(x @ router)`` over every one of ``cfg.n_experts``
+    (plus an expert bias, held at zero), the gates the chosen scores
+    normalised to sum 1 (LFM2's routing).
+
+    The scores are computed in f32 at full precision, so that the choice
+    of experts depends on the layer's input and not on how the matmul
+    rounds."""
+    logits = jnp.dot(x.astype(jnp.float32), router.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    gates, idx = jax.lax.top_k(jax.nn.sigmoid(logits), cfg.moe_top_k)
+    return idx.astype(jnp.int32), gates / gates.sum(-1, keepdims=True)
+
+
+def expert_buffer_rows(assignments: int, count: int, tile: int) -> int:
+    """Rows of the sorted buffer: every assignment landing on a held
+    expert, each of the ``count`` groups padded to the row ``tile``."""
+    return -(-assignments // tile) * tile + count * tile
+
+
+@jax.custom_vjp
+def take_rows(src, idx, inv):
+    """``out[i] = src[idx[i]]``, zero where ``idx[i] == len(src)``.
+
+    ``inv`` [len(src), m] names the rows of ``out`` that read each row of
+    ``src`` (``len(out)`` where fewer than m do), so the gradient is a
+    gather too, ``dsrc[r] = sum_j dout[inv[r, j]]``, and not the scatter-add
+    that differentiating a gather gives."""
+    return _take(src, idx)
+
+
+def _take(src, idx):
+    return jnp.concatenate([src, jnp.zeros_like(src[:1])]).at[idx].get(
+        mode="promise_in_bounds")
+
+
+def _take_fwd(src, idx, inv):
+    return _take(src, idx), (idx, inv)
+
+
+def _take_bwd(res, g):
+    idx, inv = res
+    dsrc = _take(g, inv.reshape(-1)).reshape(*inv.shape, g.shape[-1])
+    return dsrc.astype(jnp.float32).sum(1).astype(g.dtype), None, None
+
+
+take_rows.defvjp(_take_fwd, _take_bwd)
+
+
+def held_expert_ffn(x, mp: dict, cfg: ModelConfig, schedules: dict,
+                    layer: int):
+    """The routed-expert FFN of the experts this chip holds, with no
+    capacity: ``x`` [T, d] -> (partial output [T, d], counters).
+
+    Routes over all ``n_experts``; keeps the assignments that land on the
+    held experts ``cfg.held = (first, count)``, sorts them by expert and
+    pads each group to the row tile of ``schedules["experts_up"]``; one
+    grouped GEMM computes gate and up, another down, and each token sums
+    its gated rows.  The buffer has room for every assignment
+    (``expert_buffer_rows``), so none is ever dropped; its rows that no
+    held expert filled are the grouped kernels' tail and cost no tile.
+    Rows move between tokens and the buffer by gathers both ways
+    (:func:`take_rows`).  What the absent experts would add is left out:
+    the caller adds the partial output to the residual, as a chip of an
+    expert-parallel deployment does before the exchange.
+
+    ``mp``: ``router`` [d, n_experts], ``w_gate``/``w_up`` [count, d, f],
+    ``w_down`` [count, f, d].  Counters: ``rows`` [count], the assignments
+    each held expert computed; ``pad``, the padding rows of the groups.
+    Scopes: ``route<layer>`` (router, top-k, sort, the gathers into the
+    buffer and back) and ``experts<layer>`` (the grouped GEMMs)."""
+    from repro.kernels.matmul.grouped import grouped_matmul
+
+    T, d = x.shape
+    k = cfg.moe_top_k
+    A = T * k  # assignment a = token * k + slot
+    first, count = cfg.held
+    cd = x.dtype
+    up, down = schedules["experts_up"], schedules["experts_down"]
+    tile = up["fwd"].block("block_m")
+    rows = expert_buffer_rows(A, count, tile)
+    with jax.named_scope(f"route{layer}"):
+        idx, gates = route(x, mp["router"], cfg)
+        local = idx.reshape(-1) - first
+        key = jnp.where((local >= 0) & (local < count), local, count)
+        sizes = jnp.bincount(key, length=count + 1)[:count].astype(jnp.int32)
+        padded = -(-sizes // tile) * tile
+        pad_start = jnp.cumsum(padded) - padded
+        run_start = jnp.cumsum(sizes) - sizes
+        order = jnp.argsort(key, stable=True)
+        key_s = jnp.minimum(key[order], count - 1)
+        rank = jnp.arange(A, dtype=jnp.int32) - run_start[key_s]
+        dest_s = jnp.where(key[order] < count, pad_start[key_s] + rank, rows)
+        # Each held assignment's buffer row, and each row's assignment
+        # (A for padding and the tail): the two maps between them.
+        dest = jnp.full((A,), rows, jnp.int32).at[order].set(dest_s)
+        row_of = jnp.full((rows,), A, jnp.int32).at[dest_s].set(
+            order.astype(jnp.int32), mode="drop")
+        row_tok = jnp.where(row_of < A, row_of // k, T)
+        group_sizes = jnp.concatenate(
+            [padded, (rows - padded.sum())[None]]).astype(jnp.int32)
+        xr = take_rows(x, row_tok, dest.reshape(T, k))
+    with jax.named_scope(f"experts{layer}"):
+        w_gu = jnp.concatenate([mp["w_gate"], mp["w_up"]], -1).astype(cd)
+        gu = grouped_matmul(xr, w_gu, group_sizes, up)
+        g, u = jnp.split(gu, 2, axis=-1)
+        h = ll._ACT[cfg.act](g) * u
+        yr = grouped_matmul(h, mp["w_down"].astype(cd), group_sizes, down)
+    with jax.named_scope(f"route{layer}"):
+        ya = take_rows(yr, dest, row_of[:, None]).reshape(T, k, d)
+        y = (gates[..., None] * ya.astype(jnp.float32)).sum(1)
+    return y.astype(cd), {"rows": sizes, "pad": (padded - sizes).sum()}
 
 
 def layer_meta(cfg):

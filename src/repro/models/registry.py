@@ -24,12 +24,13 @@ from __future__ import annotations
 
 from jax.sharding import PartitionSpec as P
 
-from repro.models import cnn, encdec, moe, rwkv6, transformer, zamba2
+from repro.models import cnn, encdec, lfm2, moe, rwkv6, transformer, zamba2
 
 FAMILIES = {
     "dense": transformer,
     "transformer": transformer,  # the planned wing's first-class name
     "moe": moe,
+    "lfm2": lfm2,
     "rwkv6": rwkv6,
     "zamba2": zamba2,
     "encdec": encdec,

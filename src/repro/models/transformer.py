@@ -87,7 +87,8 @@ def forward(
     :func:`plan_forward`); backward overrides ride in the same dict under
     "<cell>.dx"/"<cell>.dw" keys, which :func:`plan_training` emits — so
     ``jax.grad`` through this forward runs pinned planned backward
-    kernels (attention differentiates its XLA reference)."""
+    kernels (attention's backward is the exact gradient in query blocks,
+    in XLA: :func:`_attn_bwd`)."""
     from repro.runtime.parallel import constrain
 
     if use_kernels and cache is None:
@@ -155,12 +156,83 @@ def _attn_ref(q, k, v, causal, window, schedule):
     return attention_ref(q, k, v, causal=causal, window=window)
 
 
+# Bytes of one query block's f32 scores in the attention backward.
+ATTN_BWD_BLOCK_BYTES = 256 * 2**20
+
+
+def attn_bwd_block(batch: int, heads: int, seq: int) -> int:
+    """Query rows of one block of :func:`_attn_bwd`: the largest power of
+    two whose f32 scores against every key fit ``ATTN_BWD_BLOCK_BYTES``."""
+    rows = max(8, ATTN_BWD_BLOCK_BYTES // (batch * heads * seq * 4))
+    return min(seq, 1 << (rows.bit_length() - 1))
+
+
+def _attn_bwd(q, k, v, g, causal, window, schedule):
+    """The exact gradient of softmax attention, a block of queries at a
+    time: each block's scores are recomputed against the keys it sees
+    (up to its last row under ``causal``, from its window's start under
+    ``window``), its softmax rebuilt, and dK, dV accumulated, so that no
+    [S, S] array is ever held.  Matmuls take the inputs' dtype with f32
+    accumulation; scores, softmax and the row sums are f32.  The blocks
+    run one after another."""
+    del schedule  # the forward kernel's blocking
+    B, Hq, S, D = q.shape
+    Hkv = k.shape[1]
+    grp = Hq // Hkv
+    scale = D ** -0.5
+    f32 = jnp.float32
+    qg = q.reshape(B, Hkv, grp, S, D)
+    gg = g.astype(q.dtype).reshape(B, Hkv, grp, S, D)
+    dk = jnp.zeros(k.shape, f32)
+    dv = jnp.zeros(v.shape, f32)
+    dqs = []
+    bq = attn_bwd_block(B, Hq, S)
+    for lo in range(0, S, bq):
+        hi = min(S, lo + bq)
+        k_hi = hi if causal else S
+        k_lo = max(0, lo - window + 1) if window else 0
+        # The barrier makes each block wait for the last one's dK and dV,
+        # so that one block's scores are live at a time.
+        qg, gg, k, v, dk, dv = jax.lax.optimization_barrier(
+            (qg, gg, k, v, dk, dv))
+        qb, gb = qg[..., lo:hi, :], gg[..., lo:hi, :]
+        kb, vb = k[:, :, k_lo:k_hi], v[:, :, k_lo:k_hi]
+        s = jnp.einsum("bhgqd,bhkd->bhgqk", qb, kb,
+                       preferred_element_type=f32) * scale
+        qi = jnp.arange(lo, hi)[:, None]
+        ki = jnp.arange(k_lo, k_hi)[None, :]
+        keep = jnp.ones((hi - lo, k_hi - k_lo), bool)
+        if causal:
+            keep &= ki <= qi
+        if window:
+            keep &= qi - ki < window
+        s = jnp.where(keep, s, -1e30)
+        # The row max passes a barrier: XLA would otherwise make the max
+        # and its broadcast one reduce-window as wide as the row, whose
+        # work grows with the square of the keys.
+        e = jnp.exp(s - jax.lax.optimization_barrier(s.max(-1, keepdims=True)))
+        p = e / e.sum(-1, keepdims=True)
+        pc = p.astype(q.dtype)
+        o = jnp.einsum("bhgqk,bhkd->bhgqd", pc, vb, preferred_element_type=f32)
+        delta = jnp.sum(o * gb.astype(f32), -1, keepdims=True)
+        dp = jnp.einsum("bhgqd,bhkd->bhgqk", gb, vb, preferred_element_type=f32)
+        ds = (p * (dp - delta) * scale).astype(q.dtype)
+        dqs.append(jnp.einsum("bhgqk,bhkd->bhgqd", ds, kb,
+                              preferred_element_type=f32))
+        dk = dk.at[:, :, k_lo:k_hi].add(
+            jnp.einsum("bhgqk,bhgqd->bhkd", ds, qb, preferred_element_type=f32))
+        dv = dv.at[:, :, k_lo:k_hi].add(
+            jnp.einsum("bhgqk,bhgqd->bhkd", pc, gb, preferred_element_type=f32))
+    dq = jnp.concatenate(dqs, axis=-2).reshape(q.shape)
+    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
 # The planned attention cell: forward is the flash-attention Pallas kernel
-# under its AttentionPlanner schedule, backward differentiates the XLA
-# reference composition (the flash op itself registers no custom VJP — no
-# planned attention backward exists yet; the GEMM cells do, via fc_layer).
+# under its AttentionPlanner schedule; backward is :func:`_attn_bwd`, the
+# exact gradient in query blocks, in XLA (no planned attention backward
+# kernel exists yet; the GEMM cells have theirs, via fc_layer).
 _attn_vjp = with_reference_vjp(_attn_kernel, _attn_ref,
-                               nondiff_argnums=(3, 4, 5))
+                               nondiff_argnums=(3, 4, 5), bwd_fn=_attn_bwd)
 
 
 def _forward_planned(cfg: ModelConfig, params: dict, tokens: jax.Array,

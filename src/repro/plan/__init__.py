@@ -17,6 +17,9 @@ from repro.plan.planners import (
     ConvDgradPlanner,
     ConvPlanner,
     ConvWgradPlanner,
+    GroupedMatmulDwPlanner,
+    GroupedMatmulDxPlanner,
+    GroupedMatmulPlanner,
     Im2colConvPlanner,
     MatmulDwPlanner,
     MatmulDxPlanner,
@@ -62,6 +65,9 @@ __all__ = [
     "ConvDgradPlanner",
     "ConvPlanner",
     "ConvWgradPlanner",
+    "GroupedMatmulDwPlanner",
+    "GroupedMatmulDxPlanner",
+    "GroupedMatmulPlanner",
     "Im2colConvPlanner",
     "MatmulDwPlanner",
     "MatmulDxPlanner",

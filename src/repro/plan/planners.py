@@ -25,7 +25,7 @@ import dataclasses
 from typing import ClassVar, Protocol, runtime_checkable
 
 from repro.core import ccr
-from repro.core.machine import TPU_V5E, MachineModel
+from repro.core.machine import TPU_V5E, TPU_V5E_SCOPED, MachineModel
 from repro.plan.schedule import Schedule
 from repro.plan.sharded import MeshSpec, ShardCandidate, ShardedSchedule
 
@@ -1090,8 +1090,13 @@ class MatmulDxPlanner(ShardablePlanner):
         n_k, n_n, n_m = kp // bk, np_ // bn, mp // bm
         grid = (n_k, n_n, n_m)
         stream = 0
+        acc = (mp * bk + bk * bn) * 4  # the f32 dX strip and dW tile
         if self.machine.charge_stream_blocks:
             stream = (bm * bn + bk * bn + bm * bk) * in_bytes * 2
+            # The two output blocks, the same shapes as the accumulators
+            # and written in f32 (the FC layer's backward asks f32), are
+            # double-buffered by the pipeline like the input blocks.
+            stream += 2 * acc
         return dataclasses.replace(
             sched,
             algorithm="fused_dxdw",
@@ -1099,7 +1104,7 @@ class MatmulDxPlanner(ShardablePlanner):
             macs=2 * mp * np_ * kp,
             loads=n_k * mp * np_ + n_m * kp * np_ + n_n * mp * kp,
             stores=mp * kp + kp * np_,
-            vmem_bytes=stream + (mp * bk + bk * bn) * 4,
+            vmem_bytes=stream + acc,
             critical_path_steps=ccr.grid_steps(grid),
         )
 
@@ -1414,6 +1419,163 @@ class MoeFfnPlanner(ShardablePlanner):
 
 
 # ---------------------------------------------------------------------------
+# Grouped matmul (the held experts' GEMMs)
+# ---------------------------------------------------------------------------
+
+
+def _dividing_tile(extent: int, cap: int, lane: int) -> int:
+    """The largest multiple of ``lane`` at most ``cap`` that divides
+    ``extent`` (``cap`` itself when none does): a tile the grid walks with
+    no ragged edge."""
+    for t in range(_align_down(min(cap, extent), lane), 0, -lane):
+        if extent % t == 0:
+            return t
+    return cap
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedMatmulPlanner(ShardablePlanner):
+    """Plans ``out[r] = lhs[r] @ rhs[group(r)]``: the rows of a buffer
+    sorted into ``groups`` contiguous groups (one held expert each, each
+    padded to the row tile), every group against its own ``[k, n]``
+    weight, and a tail of rows that belongs to no group and costs no tile.
+
+    Each group is one GEMM of ``group_rows`` rows, so the blocking is
+    delegated to :class:`MatmulPlanner` on that GEMM (the compound pattern
+    of :class:`TransformerBlockPlanner`), with its roles per pass: the
+    forward contracts k, the input gradient (:class:`GroupedMatmulDxPlanner`)
+    contracts n, the weight gradient (:class:`GroupedMatmulDwPlanner`) the
+    rows.  Kwargs are the forward's shapes for every pass: ``m`` buffer
+    rows, ``k`` and ``n`` the weight's, ``group_rows`` the rows a group is
+    planned for (the mean routed rows; ``m // groups`` by default).  Block
+    names keep the forward roles (``block_m`` rows, ``block_k`` the
+    weight's k, ``block_n`` its n); the row tile is the one every pass
+    shares, since the groups are padded to it.
+
+    The kernels (jax's megablox ``gmm``/``tgmm``) set no VMEM limit, so
+    they compile under the compiler's default scoped limit
+    (``TPU_V5E_SCOPED``); the planner counts every block against it, the
+    double-buffered inputs and output and the f32 accumulator, and
+    halves the output tile, then the contraction tile, until it fits.
+    Tiles divide their extents, so the grid has no ragged edge.  The
+    modeled words are those of the planned group rows: the tail and the
+    padding cost nothing in the model, and tiles of the tail are never
+    visited by the kernels.
+    """
+
+    op: ClassVar[str] = "grouped_matmul"
+    machine: MachineModel = TPU_V5E_SCOPED
+    # The one group GEMM as MatmulPlanner sees it: (m, n, k) from the
+    # group's (rows, k, n), and MatmulPlanner's block names back to the
+    # forward roles.
+    _GEMM: ClassVar[tuple] = (("rows", "n", "k"),
+                              {"block_m": "block_m", "block_n": "block_n",
+                               "block_k": "block_k"})
+
+    def _vmem(self, tiles: dict, in_bytes: int, out_bytes: int) -> int:
+        """(rows or k, contraction, out) blocks of one kernel step:
+        double-buffered operand and output blocks, f32 accumulator."""
+        a, c, o = tiles["out_rows"], tiles["contract"], tiles["out_cols"]
+        return 2 * (a * c + c * o) * in_bytes + 2 * a * o * out_bytes + a * o * 4
+
+    def _roles(self, blocks: dict) -> dict:
+        """This pass's kernel step: output rows, contraction, output cols."""
+        return {"out_rows": blocks["block_m"], "contract": blocks["block_k"],
+                "out_cols": blocks["block_n"]}
+
+    def plan_local(
+        self, *, m: int, k: int, n: int, groups: int,
+        group_rows: int | None = None, in_bytes: int = 2,
+        out_bytes: int | None = None, block_m: int | None = None,
+        block_k: int | None = None, block_n: int | None = None,
+    ) -> Schedule:
+        lane = self.machine.lane
+        out_bytes = out_bytes or in_bytes
+        rows = group_rows or max(1, m // groups)
+        dims = {"rows": rows, "k": k, "n": n}
+        (gm, gn, gk), names = self._GEMM
+        pins = {"block_m": block_m, "block_k": block_k, "block_n": block_n}
+        inv = {v: kk for kk, v in names.items()}
+        inner = MatmulPlanner(self.machine).plan_local(
+            m=dims[gm], n=dims[gn], k=dims[gk], in_bytes=in_bytes,
+            **{kk: pins[names[kk]] for kk in ("block_m", "block_n",
+                                              "block_k")})
+        blocks = {names[kk]: v for kk, v in inner.blocks}
+        extent = {"block_m": None, "block_k": k, "block_n": n}
+        for name, ext in extent.items():
+            if ext is not None and pins[name] is None:
+                blocks[name] = _dividing_tile(ext, blocks[name], lane)
+        budget = self.machine.usable_for_working_set(streams=2)
+        out_cols = inv["block_n"]  # shrink the output tile first
+        for name in (names[out_cols], names["block_k"]):
+            while (self._vmem(self._roles(blocks), in_bytes, out_bytes) > budget
+                   and blocks[name] > lane and pins[name] is None):
+                ext = extent[name]
+                blocks[name] = (_dividing_tile(ext, blocks[name] // 2, lane)
+                                if ext else blocks[name] // 2)
+        bm, bk, bn = blocks["block_m"], blocks["block_k"], blocks["block_n"]
+        rp = round_up(rows, bm)
+        kp, np_ = round_up(k, bk), round_up(n, bn)
+        grid = (np_ // bn, groups * (rp // bm), kp // bk)
+        return Schedule(
+            op=self.op,
+            grid=grid,
+            blocks=(("block_k", bk), ("block_m", bm), ("block_n", bn)),
+            macs=groups * rp * kp * np_,
+            loads=groups * ((np_ // bn) * rp * kp + (rp // bm) * kp * np_),
+            stores=groups * self._stores(rp, kp, np_),
+            vmem_bytes=self._vmem(self._roles(blocks), in_bytes, out_bytes),
+            machine=self.machine.name,
+            critical_path_steps=ccr.grid_steps(grid),
+        )
+
+    @staticmethod
+    def _stores(rp: int, kp: int, np_: int) -> int:
+        return rp * np_
+
+    def local_candidates(self, **shape) -> list[Schedule]:
+        return self._ladder_candidates("block_n", self.machine.lane, **shape)
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedMatmulDxPlanner(GroupedMatmulPlanner):
+    """The grouped matmul's input gradient, ``dlhs[r] = dout[r] @
+    rhs[group(r)]^T``: each group's GEMM contracts n and outputs k."""
+
+    op: ClassVar[str] = "grouped_matmul_dx"
+    _GEMM: ClassVar[tuple] = (("rows", "k", "n"),
+                              {"block_m": "block_m", "block_n": "block_k",
+                               "block_k": "block_n"})
+
+    def _roles(self, blocks: dict) -> dict:
+        return {"out_rows": blocks["block_m"], "contract": blocks["block_n"],
+                "out_cols": blocks["block_k"]}
+
+    @staticmethod
+    def _stores(rp: int, kp: int, np_: int) -> int:
+        return rp * kp
+
+
+@dataclasses.dataclass(frozen=True)
+class GroupedMatmulDwPlanner(GroupedMatmulPlanner):
+    """The grouped matmul's weight gradient, ``drhs[g] = lhs_g^T @
+    dout_g``: each group's GEMM contracts its rows and outputs ``[k, n]``."""
+
+    op: ClassVar[str] = "grouped_matmul_dw"
+    _GEMM: ClassVar[tuple] = (("k", "n", "rows"),
+                              {"block_m": "block_k", "block_n": "block_n",
+                               "block_k": "block_m"})
+
+    def _roles(self, blocks: dict) -> dict:
+        return {"out_rows": blocks["block_k"], "contract": blocks["block_m"],
+                "out_cols": blocks["block_n"]}
+
+    @staticmethod
+    def _stores(rp: int, kp: int, np_: int) -> int:
+        return kp * np_
+
+
+# ---------------------------------------------------------------------------
 # Transformer block (compound planner: the whole wing through delegation)
 # ---------------------------------------------------------------------------
 
@@ -1505,6 +1667,9 @@ PLANNERS: dict[str, type] = {
     MatmulDwPlanner.op: MatmulDwPlanner,
     AttentionPlanner.op: AttentionPlanner,
     MoeFfnPlanner.op: MoeFfnPlanner,
+    GroupedMatmulPlanner.op: GroupedMatmulPlanner,
+    GroupedMatmulDxPlanner.op: GroupedMatmulDxPlanner,
+    GroupedMatmulDwPlanner.op: GroupedMatmulDwPlanner,
     TransformerBlockPlanner.op: TransformerBlockPlanner,
 }
 

@@ -269,6 +269,9 @@ _PROVIDERS = {
     "matmul_dx": "repro.kernels.matmul.bwd",
     "matmul_dw": "repro.kernels.matmul.bwd",
     "flash_attention": "repro.kernels.flash_attention.ops",
+    "grouped_matmul": "repro.kernels.matmul.grouped",
+    "grouped_matmul_dx": "repro.kernels.matmul.grouped",
+    "grouped_matmul_dw": "repro.kernels.matmul.grouped",
 }
 
 

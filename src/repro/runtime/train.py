@@ -112,6 +112,15 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, parallel=None,
     """
     loss_fn = make_loss_fn(cfg, tcfg, parallel)
 
+    def loss_and_counters(params, batch):
+        # A family's loss may return (loss, counters): integer counts of
+        # what the step did (rows each held expert computed), carried
+        # out of the step beside the loss.
+        out = loss_fn(params, batch)
+        return out if isinstance(out, tuple) else (out, {})
+
+    grad_fn = jax.value_and_grad(loss_and_counters, has_aux=True)
+
     def _pin(tree):
         if grad_specs is None or parallel is None:
             return tree
@@ -127,16 +136,18 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, parallel=None,
 
             def acc(carry, mb):
                 gsum, lsum = carry
-                l, g = jax.value_and_grad(loss_fn)(params, mb)
+                (l, c), g = grad_fn(params, mb)
                 gsum = jax.tree.map(lambda a, b: a + b.astype(jnp.float32), gsum, g)
-                return (_pin(gsum), lsum + l), None
+                return (_pin(gsum), lsum + l), c
 
             g0 = _pin(jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params))
-            (grads, loss), _ = jax.lax.scan(acc, (g0, jnp.zeros(())), batch)
+            (grads, loss), counters = jax.lax.scan(acc, (g0, jnp.zeros(())),
+                                                   batch)
+            counters = jax.tree.map(lambda c: c.sum(0), counters)
             grads = jax.tree.map(lambda g: g / n, grads)
             loss = loss / n
         else:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
+            (loss, counters), grads = grad_fn(params, batch)
             grads = _pin(jax.tree.map(lambda g: g.astype(jnp.float32), grads))
 
         err = state.err
@@ -145,11 +156,56 @@ def make_train_step(cfg: ModelConfig, tcfg: TrainConfig, parallel=None,
                 jax.tree.map(lambda g: g.astype(jnp.float32), grads), err
             )
 
-        params, opt, metrics = adamw.apply_updates(params, grads, state.opt, tcfg)
+        new_params, opt, metrics = adamw.apply_updates(params, grads, state.opt,
+                                                       tcfg)
+        # A non-finite loss or gradient leaves the state as it was, here on
+        # the device: a step that is given the optimizer state to update in
+        # place (:class:`DonatingStep`) leaves the loop nothing to put back.
+        # The choice fuses into the update, so it runs under its scope.
+        with jax.named_scope("optimizer"):
+            ok = jnp.isfinite(loss) & jnp.isfinite(metrics["grad_norm"])
+            params, opt = jax.tree.map(
+                lambda new, old: jnp.where(ok, new, old),
+                (new_params, opt), (params, state.opt))
         metrics = dict(metrics, loss=loss)
+        if counters:
+            metrics["counters"] = counters
         return TrainState(params, opt, err), metrics
 
     return train_step
+
+
+class DonatingStep:
+    """A train step, ``(state, batch) -> (state, metrics)``, jitted with the
+    optimizer state donated: the moments are updated in place, so a step
+    holds one copy of them (8 bytes a parameter) and not two.  The
+    parameters are not donated, so a caller may keep an earlier step's
+    (a comparison with a reference does).  A step that sees a non-finite
+    loss or gradient returns the state it was given (``make_train_step``),
+    so nothing is lost with the donated buffers.  ``lower(state, batch)``
+    lowers the step as ``jax.jit``'s does."""
+
+    def __init__(self, step_fn: Callable, in_shardings=None):
+        def on_parts(params, opt, err, batch):
+            return step_fn(TrainState(params, opt, err), batch)
+
+        kw = {}
+        if in_shardings is not None:
+            state, batch = in_shardings
+            kw["in_shardings"] = (state.params, state.opt, state.err, batch)
+        self._jit = jax.jit(on_parts, donate_argnums=(1,), **kw)
+
+    def __call__(self, state: TrainState, batch: dict):
+        return self._jit(state.params, state.opt, state.err, batch)
+
+    def lower(self, state: TrainState, batch: dict):
+        return self._jit.lower(state.params, state.opt, state.err, batch)
+
+
+def _donated(tree) -> bool:
+    """Whether a buffer of ``tree`` was given to a step that donated it."""
+    return any(isinstance(x, jax.Array) and x.is_deleted()
+               for x in jax.tree.leaves(tree))
 
 
 def init_state(cfg: ModelConfig, tcfg: TrainConfig, params) -> TrainState:
@@ -237,11 +293,15 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
     prefetched batch stays after a skipped update, since data does not
     depend on state, and any recovery drops it (the rebuilt run fetches
     its start afresh).  The source is never called for a step the call
-    does not run, and no state buffer is donated: the rollback needs the
-    previous state alive.
+    does not run.  Where the step donated the previous state's optimizer
+    buffers (:class:`DonatingStep`), the step itself returned that state
+    for a non-finite loss, and it stays; a loss made non-finite on the host
+    alone (the chaos monkey's) then skips nothing.
 
     Returns ``(final_state, history)`` where history is one record per
-    *executed* step: its ``time`` is the ``step`` span
+    *executed* step (with the step's ``counters`` where its loss gives
+    any, e.g. the rows each held expert computed): its ``time`` is the
+    ``step`` span
     (:mod:`repro.runtime.spans`), which holds the step's dispatch and
     wait and, but for the last step of a call, the next step's input;
     ``input_s``, ``dispatch_s`` and ``wait_s`` are the spans of the
@@ -363,7 +423,8 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
                 slow = 0
 
             if not math.isfinite(loss):
-                run.state = prev  # the update is skipped
+                if not _donated(prev):
+                    run.state = prev  # the update is skipped
                 bad += 1
                 log(f"  [guard] step {step}: non-finite loss — update "
                     f"skipped ({bad}/{policy.nonfinite_patience})")
@@ -380,12 +441,19 @@ def run_elastic(build: Callable, source: Callable, steps: int, *,
             bad = 0
             recoveries = 0  # the cap is on CONSECUTIVE recoveries:
             # a committed step in between proves real progress
-            history.append({"step": step, "loss": loss, **times,
-                            "skipped": False})
+            record = {"step": step, "loss": loss, **times, "skipped": False}
+            if "counters" in metrics:
+                record["counters"] = jax.tree.map(
+                    lambda c: c.tolist(), jax.device_get(metrics["counters"]))
+            history.append(record)
             if step % run.log_every == 0 or step == steps - 1:
                 extra = "".join(
                     f"  {k} {float(metrics[k]):.3g}"
                     for k in ("grad_norm", "lr") if k in metrics)
+                extra += "".join(
+                    f"  {name} rows {min(c['rows'])}-{max(c['rows'])} "
+                    f"(sum {sum(c['rows'])}) pad {c['pad']}"
+                    for name, c in record.get("counters", {}).items())
                 log(f"step {step:5d}  loss {loss:.4f}{extra}  {dt:.2f}s "
                     f"(input {input_s * 1e3:.1f} ms"
                     f"{' prefetched' if prefetched else ''}, dispatch "

@@ -239,3 +239,94 @@ def test_planned_train_step_compiles(topo, one_chip, no_cache, monkeypatch):
     # stage pattern above does not take for a stage.
     for scope in ("loss", "optimizer"):
         assert re.search(rf'op_name="[^"]*[/(]{scope}[/)]', hlo), scope
+
+
+@pytest.fixture(scope="module")
+def lfm2_plan():
+    from repro.configs.registry import get_config
+    from repro.models import lfm2
+
+    # The lfm2-8b-a1b share's step at batch 2 x 8192, bf16.
+    return lfm2.plan_training(get_config("lfm2-8b-a1b-ep4"), 2, 8192,
+                              loss_chunks=4, in_bytes=2)
+
+
+@pytest.mark.parametrize("cell,k,n", [("experts_up", 2048, 3584),
+                                      ("experts_down", 1792, 2048)])
+@pytest.mark.parametrize("direction", ("fwd", "dx", "dw"))
+def test_grouped_matmul_compiles(topo, one_chip, no_cache, lfm2_plan, cell,
+                                 k, n, direction):
+    """The held experts' grouped GEMMs at the published widths, every
+    pass, under the compiler's default scoped VMEM (megablox sets none)."""
+    from repro.plan import get_op
+
+    s = lfm2_plan[cell][direction]
+    m = 16384 * 4 + 8 * s.block("block_m")
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    sizes = spec((9,), jnp.int32)
+    if direction == "fwd":
+        fn = lambda x, w, gs: get_op("grouped_matmul")(  # noqa: E731
+            x, w, gs, schedule=s, interpret=False)
+        args = spec((m, k)), spec((8, k, n)), sizes
+    elif direction == "dx":
+        fn = lambda g, w, gs: get_op("grouped_matmul_dx")(  # noqa: E731
+            g, w, gs, schedule=s, interpret=False)
+        args = spec((m, n)), spec((8, k, n)), sizes
+    else:
+        fn = lambda x, g, gs: get_op("grouped_matmul_dw")(  # noqa: E731
+            x, g, gs, schedule=s, groups=8, out_dtype=jnp.float32,
+            interpret=False)
+        args = spec((m, k)), spec((m, n)), sizes
+    assert "tpu_custom_call" in _compile(fn, *args)
+
+
+def test_lfm2_attention_forward_compiles(topo, one_chip, no_cache, lfm2_plan):
+    from repro.kernels.flash_attention.ops import flash_attention
+
+    q = jax.ShapeDtypeStruct((2, 32, 8192, 64), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, 8, 8192, 64), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def fwd(q, k, v):
+        return flash_attention(q, k, v, causal=True,
+                               schedule=lfm2_plan["attn"], interpret=False)
+
+    assert "tpu_custom_call" in _compile(fwd, q, kv, kv)
+
+
+@pytest.mark.parametrize("cell", ("in_proj", "out_proj", "qkv", "wo",
+                                  "ffn_up", "ffn_down", "logits"))
+def test_lfm2_gemm_forward_and_backward_compile(topo, one_chip, no_cache,
+                                                lfm2_plan, cell):
+    """Every fc_layer GEMM of the lfm2 share's step, forward and its
+    planned backward (fused dX/dW where the planner's count fits)."""
+    from repro.configs.registry import get_config
+    from repro.kernels.matmul.bwd import matmul_dw, matmul_dx, matmul_dx_dw
+    from repro.kernels.matmul.ops import fc_matmul
+    from repro.models import lfm2
+
+    m, k, n = lfm2.gemm_shapes(get_config("lfm2-8b-a1b-ep4"), 2, 8192,
+                               loss_chunks=4)[cell]
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
+
+    x, w, g = spec(m, k), spec(k, n), spec(m, n)
+    fwd = lambda x, w: fc_matmul(  # noqa: E731
+        x, w, schedule=lfm2_plan[cell], interpret=False)
+    assert "tpu_custom_call" in _compile(fwd, x, w)
+    s_dx, s_dw = lfm2_plan[f"{cell}.dx"], lfm2_plan[f"{cell}.dw"]
+    if s_dx.algorithm == "fused_dxdw":
+        bwd = lambda g, w, x: matmul_dx_dw(  # noqa: E731
+            g, w, x, schedule=s_dx, out_dtype=jnp.float32, interpret=False)
+    else:
+        bwd = lambda g, w, x: (  # noqa: E731
+            matmul_dx(g, w, schedule=s_dx, out_dtype=jnp.float32,
+                      interpret=False),
+            matmul_dw(x, g, schedule=s_dw, out_dtype=jnp.float32,
+                      interpret=False))
+    assert "tpu_custom_call" in _compile(bwd, g, w, x)

@@ -177,4 +177,32 @@ def test_the_launchers_step_donates_no_state_buffer():
                                             jnp.float32),
              "labels": jax.ShapeDtypeStruct((2,), jnp.int32)}
     lowered = result.step_fn.lower(result.state, batch)
-    assert not any(a.donated for a in jax.tree.leaves(lowered.args_info))
+    (params, opt, err, fed), _ = lowered.args_info
+    # No buffer that a caller may keep is donated: the parameters (a
+    # comparison holds an earlier step's), the batch.  The optimizer's
+    # moments are updated in place.
+    assert not any(a.donated for a in jax.tree.leaves((params, err, fed)))
+    assert all(a.donated for a in jax.tree.leaves((opt.m, opt.v)))
+
+
+def test_a_non_finite_step_returns_the_state_it_was_given():
+    """The optimizer state is donated to the step, so the step itself
+    skips an update whose loss is not finite."""
+    from repro.configs.base import TrainConfig
+    from repro.configs.registry import smoke_config
+    from repro.models import cnn
+    from repro.models.module import init_params
+
+    cfg = smoke_config("cnn-vgg11")
+    tcfg = TrainConfig(param_dtype="float32", compute_dtype="float32",
+                       loss_chunks=1)
+    state = tr.init_state(cfg, tcfg, init_params(
+        cnn.param_defs(cfg), jax.random.PRNGKey(0), jnp.float32))
+    want = jax.tree.map(np.asarray, state)
+    step = tr.DonatingStep(tr.make_train_step(cfg, tcfg))
+    batch = {"images": jnp.full((2, cnn.IMG, cnn.IMG, cnn.IN_CH), jnp.nan),
+             "labels": jnp.zeros((2,), jnp.int32)}
+    new, metrics = step(state, batch)
+    assert not np.isfinite(float(metrics["loss"]))
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(want)):
+        np.testing.assert_array_equal(np.asarray(a), b)
