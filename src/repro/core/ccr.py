@@ -451,6 +451,54 @@ def conv_im2col_traffic(*, H_O: int, W_O: int, F: int, S: int, d_in: int,
     return Traffic(macs=macs, main_loads=loads, main_stores=stores)
 
 
+def conv_rows_geometry(*, W_O: int, F: int, d_in: int, d_out: int,
+                       pool: int = 1, lane: int = 1) -> tuple[int, int, int]:
+    """``(K, Nh, V)`` of the row-GEMM conv family (stride 1): K lanes hold
+    one padded input row's (W_O + F - 1) * d_in words, each of the
+    ``pool`` column groups of the banded filter matrix holds
+    (W_O / pool) * d_out output words in Nh lanes, and V = F + pool - 1
+    row views of the input feed the pool's row phases."""
+    K = math.ceil((W_O + F - 1) * d_in / lane) * lane
+    Nh = math.ceil((W_O // pool) * d_out / lane) * lane
+    return K, Nh, F + pool - 1
+
+
+def conv_rows_traffic(*, H_O: int, W_O: int, F: int, d_in: int, d_out: int,
+                      pool: int = 1, batch: int = 1, lane: int = 1,
+                      row_tile: int = 1, kind: str = "fwd") -> Traffic:
+    """Words and MXU work of one pass of the row-GEMM conv family.
+
+    The stage runs as F row-GEMMs in a (W * d_in)-in-lanes layout: each
+    image's V row views ``[Hq, K]`` (Hq = H_O / pool rows rounded up to
+    ``row_tile``) multiply the F banded (block-Toeplitz) filter matrices
+    ``[K, pool * Nh]`` (:func:`conv_rows_geometry`).  The MACs count
+    every MXU pass, the band's zeros included: K spans the whole padded
+    row where a tap needs F * d_in of it, so a wide plane of deep
+    channels costs far more than the direct kernel, while a narrow input
+    (the images' 3 channels) fills lanes the direct kernel pads with
+    zeros.  The filter matrices load once (their block never changes).
+
+    ``kind`` is the pass: "fwd" reads the views and the band and stores
+    the pooled output; "wgrad" reads the views and the pooled gradient
+    and stores the band's gradient; "dgrad" reads the pooled gradient and
+    the band and stores the views' gradient.  The int8 pool mask that the
+    forward stores and both backward passes read is not counted, as in
+    the direct family."""
+    K, Nh, V = conv_rows_geometry(W_O=W_O, F=F, d_in=d_in, d_out=d_out,
+                                  pool=pool, lane=lane)
+    Hq = math.ceil(H_O // pool / row_tile) * row_tile
+    views = batch * V * Hq * K
+    out = batch * Hq * Nh
+    band = F * pool * K * Nh
+    macs = batch * pool * pool * F * Hq * K * Nh
+    loads, stores = {
+        "fwd": (views + band + Nh, out),
+        "wgrad": (views + out, band + Nh),
+        "dgrad": (out + band, views),
+    }[kind]
+    return Traffic(macs=macs, main_loads=loads, main_stores=stores)
+
+
 def ring_traffic(*, m: int, n: int, k: int, devices: int) -> Traffic:
     """Alg 3's ring reuse on the FC/matmul mesh (core/ring.py): X is
     K-sharded, W is N-sharded with full K, and each device multiplies the

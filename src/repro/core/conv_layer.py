@@ -173,47 +173,34 @@ def conv_layer(x, f, stride=1, padding=0, strategy="alg2",
                            freeze_schedules(bwd_schedules))
 
 
-def _conv_block_kernel(x, f, b, stride, padding, pool, strategy, schedule,
+def _conv_block_kernel(x, f, b, stride, padding, pool, schedule,
                        bwd_schedules):
     del bwd_schedules  # consumed by the backward pass
-    block_do, block_h = _strategy_blocks(strategy, x, f, stride, padding)
-    return conv2d(
-        x, f, bias=b, stride=stride, padding=padding,
-        relu=True, pool=pool, schedule=schedule,
-        block_do=block_do, block_h=block_h,
-    )
+    return conv2d(x, f, bias=b, stride=stride, padding=padding, relu=True,
+                  pool=pool, schedule=schedule)
 
 
-def _conv_block_ref(x, f, b, stride, padding, pool, strategy, schedule,
-                    bwd_schedules):
-    del strategy, schedule, bwd_schedules
+def _conv_block_ref(x, f, b, stride, padding, pool, schedule, bwd_schedules):
+    del schedule, bwd_schedules
     return conv2d_fused_ref(
         x, f, b, stride=stride, padding=padding, relu=True, pool=pool
     )
 
 
-def _conv_block_fwd(x, f, b, stride, padding, pool, strategy, schedule,
-                    bwd_schedules):
+def _conv_block_fwd(x, f, b, stride, padding, pool, schedule, bwd_schedules):
     """The differentiated forward: same output as the primal kernel, plus
     the int8 epilogue-VJP mask as the auxiliary residual (None on the
     paths the fused flush can't emit it — im2col schedules, ragged pool
     tails — where the backward recomputes as before)."""
     del bwd_schedules  # consumed by the backward pass
-    block_do, block_h = _strategy_blocks(strategy, x, f, stride, padding)
-    if schedule is None:
-        bias = b if b is not None else jnp.zeros((f.shape[3],), jnp.float32)
-        schedule = get_op("conv2d").plan(
-            x, f, bias, stride=stride, padding=padding, relu=True,
-            pool=pool, block_do=block_do, block_h=block_h)
-    schedule = local_schedule(schedule)
     return conv2d_with_mask(
         x, f, bias=b, stride=stride, padding=padding, pool=pool,
         schedule=schedule)
 
 
-def _conv_block_bwd(x, f, b, aux, g, stride, padding, pool, strategy,
-                    schedule, bwd_schedules):
-    del strategy, schedule
+def _conv_block_bwd(x, f, b, aux, g, stride, padding, pool, schedule,
+                    bwd_schedules):
+    del schedule
     sd = dict(bwd_schedules or ())
     g = g.astype(jnp.float32)
     if aux is not None:
@@ -275,7 +262,7 @@ def _conv_block_bwd(x, f, b, aux, g, stride, padding, pool, strategy,
 
 
 _conv_block_vjp = with_reference_vjp(
-    _conv_block_kernel, _conv_block_ref, nondiff_argnums=(3, 4, 5, 6, 7, 8),
+    _conv_block_kernel, _conv_block_ref, nondiff_argnums=(3, 4, 5, 6, 7),
     bwd_fn=_conv_block_bwd, fwd_fn=_conv_block_fwd,
 )
 
@@ -290,10 +277,55 @@ def conv_block(x, f, b, stride=1, padding=0, pool=1, strategy="strip",
     ``f``: [F, F, D_I, D_O]; ``b``: [D_O].  An explicit ``schedule``
     overrides the strategy's planner constraints; ``bwd_schedules``
     ({"dgrad"/"wgrad"/"recompute": Schedule}) pins the planned backward.
+    A ``"rows"`` schedule (the planner's pick for inputs far narrower
+    than a lane) runs the row-GEMM stage of kernels/conv2d/rows.py,
+    forward and backward, wherever the pool tiles the plane.
     """
-    return _conv_block_vjp(x, f, b, stride, padding, pool, strategy,
-                           local_schedule(schedule),
+    from repro.kernels.conv2d.ops import _fused_pool, conv_out_extent
+
+    schedule = local_schedule(schedule)
+    if schedule is None:
+        block_do, block_h = _strategy_blocks(strategy, x, f, stride, padding)
+        bias = b if b is not None else jnp.zeros((f.shape[3],), jnp.float32)
+        schedule = local_schedule(get_op("conv2d").plan(
+            x, f, bias, stride=stride, padding=padding, relu=True,
+            pool=pool, block_do=block_do, block_h=block_h))
+    F = f.shape[0]
+    H_O = conv_out_extent(x.shape[-3], padding, F, stride)
+    W_O = conv_out_extent(x.shape[-2], padding, F, stride)
+    if schedule.algorithm == "rows" and _fused_pool(H_O, W_O, pool) == pool:
+        return _conv_rows_block(x, f, b, padding, pool, schedule,
+                                dict(bwd_schedules or ()))
+    return _conv_block_vjp(x, f, b, stride, padding, pool, schedule,
                            freeze_schedules(bwd_schedules))
+
+
+def _conv_rows_block(x, f, b, padding, pool, schedule, sd):
+    """The rows family's stage, its backward schedules from ``sd`` where
+    they are rows schedules, else planned for the same machine."""
+    from repro.kernels.conv2d.ops import conv_out_extent
+    from repro.kernels.conv2d.rows import conv_rows_block
+    from repro.plan import default_interpret, require_fit
+    from repro.plan.planners import plan_rows
+
+    F = f.shape[0]
+    shape = dict(H_O=conv_out_extent(x.shape[-3], padding, F, 1),
+                 W_O=conv_out_extent(x.shape[-2], padding, F, 1), F=F,
+                 d_in=f.shape[2], d_out=f.shape[3],
+                 in_bytes=x.dtype.itemsize, pool=pool,
+                 batch=x.shape[0] if x.ndim == 4 else 1)
+    machine = machine_named(schedule.machine, _BWD_MACHINE)
+    bwd = {}
+    for role, op in (("wgrad", "conv2d_wgrad"), ("dgrad", "conv2d_dgrad")):
+        s = local_schedule(sd.get(role))
+        if s is None or s.algorithm != "rows":
+            s = plan_rows(op, machine, role, **shape)
+        bwd[role] = s
+    interpret = default_interpret(None)
+    for s in (schedule, *bwd.values()):
+        require_fit(s, interpret)
+    return conv_rows_block(x, f, b, padding=padding, pool=pool,
+                           schedule=schedule, interpret=interpret, **bwd)
 
 
 def plan(
@@ -311,7 +343,7 @@ def plan(
     ``autotune`` ("off" | "cache-only" | "tune", default the process
     policy) lets a measured winner for this cell override the argmin.
     ``algorithm`` pins one family of the two-level argmin ("direct" /
-    "im2col"); the default lets both compete — the paper strategies
+    "im2col" / "rows"); the default lets them compete — the paper strategies
     ("alg1"/"alg2"/"alg3") pin direct-kernel blocks and therefore already
     imply the direct family."""
     from repro.core.machine import TPU_V5E
@@ -353,7 +385,9 @@ def plan_bwd(
     residual), the dgrad cell is planned as its ``fused_epilogue``
     variant — dY scatters through the saved mask inside the kernels —
     and the "recompute" entry is dropped entirely (recompute_words = 0).
-    A ragged pool (or ``pool=None``) keeps today's recompute plan.
+    A ragged pool (or ``pool=None``) keeps today's recompute plan.  Where
+    the forward plans the rows family (fused pool), "dgrad" and "wgrad"
+    are that family's backward kernels.
 
     Geometries outside the dgrad kernel's contract (padding > F-1, where
     the layer trains via the XLA fallback) return only the plannable
@@ -379,6 +413,16 @@ def plan_bwd(
         return at.resolve(op, shape, machine=machine, mesh=mesh,
                           axis=shard_axis, policy=autotune)
 
+    if fused and plan(x_shape, f_shape, stride=stride, padding=padding,
+                      pool=pool, in_bytes=in_bytes, machine=machine,
+                      mesh=mesh, shard_axis=shard_axis,
+                      autotune=autotune).algorithm == "rows":
+        # The rows forward's backward kernels read its mask and views.
+        return {
+            role: res(f"conv2d_{role}", H_O=H_O, W_O=W_O, F=F, S=stride,
+                      d_in=d_in, d_out=d_out, in_bytes=in_bytes, batch=B,
+                      pool=pool, algorithm="rows")
+            for role in ("wgrad", "dgrad")}
     out = {
         "wgrad": res(
             "conv2d_wgrad",

@@ -32,6 +32,9 @@ numerics, serialized streams):
 * wgrad ``"pipelined"`` folds the whole (batch, strip) accumulation sweep
   inside each (d_i, d_o) step the same way.
 
+A ``"rows"`` tag runs the row-GEMM family's backward kernels instead
+(kernels/conv2d/rows.py), behind its forward's mask.
+
 Both wrappers compute on a width padded up to the sublane tile
 (:func:`_sublane`): the manual DMA copies whole rows of the padded input,
 and the TPU only copies slices whose second-minor extent is a multiple of
@@ -338,6 +341,13 @@ def _dgrad_impl(dy, f, *, schedule, out_dtype, interpret, stride=1, padding=0,
                 out_hw=None, mask=None, pool=1, block_h=None, block_do=None,
                 block_di=None):
     del block_h, block_do, block_di  # consumed by the planner
+    if schedule.algorithm == "rows":  # behind the row-GEMM forward
+        from repro.kernels.conv2d.rows import conv2d_rows_dgrad
+
+        return conv2d_rows_dgrad(
+            dy, f, mask, stride=stride, padding=padding, out_hw=out_hw,
+            pool=pool, schedule=schedule, out_dtype=out_dtype,
+            interpret=interpret)
     return _dgrad_impl_jit(
         dy, f, mask, stride=stride, padding=padding, out_hw=out_hw,
         pool=pool, schedule=schedule, out_dtype=out_dtype,
@@ -700,6 +710,12 @@ def _wgrad_impl(x, dy, *, schedule, out_dtype, interpret, F, stride=1,
                 padding=0, mask=None, pool=1, block_h=None, block_do=None,
                 block_di=None):
     del block_h, block_do, block_di  # consumed by the planner
+    if schedule.algorithm == "rows":  # behind the row-GEMM forward
+        from repro.kernels.conv2d.rows import conv2d_rows_wgrad
+
+        return conv2d_rows_wgrad(
+            x, dy, mask, F=F, stride=stride, padding=padding, pool=pool,
+            schedule=schedule, out_dtype=out_dtype, interpret=interpret)
     return _wgrad_impl_jit(
         x, dy, mask, F=F, stride=stride, padding=padding, pool=pool,
         schedule=schedule, out_dtype=out_dtype, interpret=interpret,

@@ -140,12 +140,18 @@ def _conv2d_impl(
 def _local_impl(x, f, bias, *, schedule, **kw):
     """Algorithm dispatch off the schedule tag: a two-level-argmin (or
     cache-replayed) schedule carrying ``algorithm="im2col"`` executes the
-    patch-matrix GEMM kernel; everything else runs the direct strip
-    kernel.  The import is lazy so the two conv modules stay acyclic."""
-    if getattr(schedule, "algorithm", "direct") == "im2col":
+    patch-matrix GEMM kernel, ``algorithm="rows"`` the row-GEMM kernel;
+    everything else runs the direct strip kernel.  The imports are lazy
+    so the conv modules stay acyclic."""
+    algorithm = getattr(schedule, "algorithm", "direct")
+    if algorithm == "im2col":
         from repro.kernels.conv2d.im2col import _conv2d_im2col_impl
 
         return _conv2d_im2col_impl(x, f, bias, schedule=schedule, **kw)
+    if algorithm == "rows":
+        from repro.kernels.conv2d.rows import conv2d_rows
+
+        return conv2d_rows(x, f, bias, schedule=schedule, **kw)
     return _conv2d_impl(x, f, bias, schedule=schedule, **kw)
 
 
@@ -271,11 +277,12 @@ def conv2d_with_mask(
     Identical output to :func:`conv2d` with ``relu=True``, plus the int8
     per-output-pixel mask (pool argmax position, or the ReLU liveness bit
     when ``pool == 1``) the backward pass scatters ``dY`` through instead
-    of recomputing the convolution.  Returns ``(out, None)`` — same
-    ``out``, no mask — on the paths the strip kernel's flush can't serve:
-    an ``algorithm="im2col"`` schedule and the ragged-pool tail (``H_O`` or
-    ``W_O`` not divisible by ``pool``); callers fall back to the recompute
-    backward there.
+    of recomputing the convolution.  A ``algorithm="rows"`` schedule runs
+    the row-GEMM kernel, whose flush stores the same mask.  Returns
+    ``(out, None)`` — same ``out``, no mask — on the paths no flush can
+    serve: an ``algorithm="im2col"`` schedule and the ragged-pool tail
+    (``H_O`` or ``W_O`` not divisible by ``pool``); callers fall back to
+    the recompute backward there.
     """
     from repro.plan import default_interpret, require_fit
 
@@ -300,6 +307,13 @@ def conv2d_with_mask(
         return out, None
     interpret = default_interpret(interpret)
     require_fit(schedule, interpret)
+    if schedule.algorithm == "rows":
+        from repro.kernels.conv2d.rows import conv2d_rows
+
+        return conv2d_rows(
+            x, f, bias, stride=stride, padding=padding, relu=True, pool=pool,
+            schedule=schedule, out_dtype=out_dtype or x.dtype,
+            interpret=interpret, emit_mask=True)
     return _conv2d_impl(
         x, f, bias, stride=stride, padding=padding, relu=True, pool=pool,
         schedule=schedule, out_dtype=out_dtype or x.dtype,

@@ -75,6 +75,19 @@ class LaunchResult:
     step_fn: Callable
 
 
+def rows_stages_line(plan: dict) -> str | None:
+    """The plan log's count of conv stages that run the row-GEMM family
+    (``kernels/conv2d/rows.py``), which the planner picks by shape; None
+    for a plan with no conv stage."""
+    convs = sorted(k for k, s in plan.items()
+                   if getattr(s, "op", None) == "conv2d")
+    if not convs:
+        return None
+    rows = [k for k in convs if plan[k].algorithm == "rows"]
+    return (f"conv stages on the rows family: {len(rows)} of {len(convs)} "
+            f"({', '.join(rows) or 'none'})")
+
+
 def main(argv: Sequence[str] | None = None) -> LaunchResult:
     """Run the launcher on ``argv`` (``sys.argv[1:]`` when None)."""
     ap = argparse.ArgumentParser()
@@ -258,6 +271,13 @@ def main(argv: Sequence[str] | None = None) -> LaunchResult:
             ici = sum(s.ici_words for s in splan.values())
             print(f"sharded plan: {len(splan)} kernels | modeled step words "
                   f"hbm={hbm} ici={ici}")
+        if tcfg.planned_kernels and hasattr(fam, "plan_training"):
+            plan = splan if use_sharding else fam.plan_training(
+                cfg, args.batch, seq=args.seq, loss_chunks=tcfg.loss_chunks,
+                in_bytes=jnp.dtype(tcfg.compute_dtype).itemsize)
+            line = rows_stages_line(plan)
+            if line:
+                print(f"plan: {line}")
 
         step_fn = tr.make_train_step(
             cfg, tcfg, parallel=ctx if use_sharding else None,

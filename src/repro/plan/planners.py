@@ -22,6 +22,7 @@ to legal ranges) so a schedule can also *describe* a hand-picked blocking.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import ClassVar, Protocol, runtime_checkable
 
 from repro.core import ccr
@@ -243,6 +244,90 @@ def conv_strip_words(
     return batch * loads, batch * stores
 
 
+_ROWS_M = 512  # rows of one row-GEMM block: images x pooled rows
+_ROWS_VALUE = 128 * 1024  # f32 elements of one in-kernel [rows, cols] value
+
+
+def rows_applies(machine: MachineModel, S: int) -> bool:
+    """The row-GEMM family exists to fill lanes the direct kernel pads
+    with zeros: it runs stride 1 on a machine with lanes."""
+    return S == 1 and machine.lane > 1
+
+
+def plan_rows(
+    op: str, machine: MachineModel, kind: str, *, H_O: int, W_O: int, F: int,
+    d_in: int, d_out: int, in_bytes: int = 2, pool: int = 1, batch: int = 1,
+    block_b: int | None = None, block_c: int | None = None,
+) -> Schedule:
+    """One pass (``kind``: "fwd", "wgrad" or "dgrad") of the row-GEMM conv
+    family (kernels/conv2d/rows.py): grid over blocks of ``block_b``
+    images, each step computing ``block_b * Hq`` rows against the resident
+    banded filter matrices in column chunks of ``block_c`` lanes.  Words
+    and MXU passes come from ``ccr.conv_rows_traffic``; the working set
+    counts the double-buffered blocks and the chunk's f32 values."""
+    lane = machine.lane
+    K, Nh, V = ccr.conv_rows_geometry(W_O=W_O, F=F, d_in=d_in, d_out=d_out,
+                                      pool=pool, lane=lane)
+    row_tile = 8 * max(1, 4 // in_bytes)
+    Hq = round_up(H_O // pool, row_tile)
+    band = F * pool * K * Nh
+
+    def vmem(nb: int, cn: int) -> int:
+        M = nb * Hq
+        x, g, mask = V * M * K * in_bytes, M * Nh * in_bytes, M * Nh
+        if kind == "fwd":
+            blocks = x + band * in_bytes + Nh * 4 + g + mask
+            values = (pool * pool + 1) * M * cn * 4
+        elif kind == "wgrad":
+            blocks = x + g + mask + band * 4 + Nh * 4
+            values = 2 * M * cn * 4 + K * cn * 4
+        else:
+            blocks = g + mask + band * in_bytes + x
+            values = V * M * K * 4 + M * cn * 4
+        return 2 * blocks + values
+
+    # Images per block: enough rows for the MXU, a whole number of int8
+    # mask tiles (32 rows) unless one block holds the batch.
+    nb = block_b or min(batch, max(1, _ROWS_M // Hq))
+    if nb < batch:
+        nb = min(batch, round_up(nb, 32 // math.gcd(Hq, 32)))
+    cn = block_c or Nh
+    if block_c is None:
+        while cn > lane and (nb * Hq * cn > _ROWS_VALUE or Nh % cn):
+            cn -= lane
+    budget = machine.usable_for_working_set(streams=2)
+    while block_b is None and nb > 1 and vmem(nb, cn) > budget:
+        nb = max(1, nb // 2)
+    grid = (-(-batch // nb),)
+    t = ccr.conv_rows_traffic(H_O=H_O, W_O=W_O, F=F, d_in=d_in, d_out=d_out,
+                              pool=pool, batch=grid[0] * nb, lane=lane,
+                              row_tile=row_tile, kind=kind)
+    return Schedule(
+        op=op,
+        grid=grid,
+        blocks=(("block_b", nb), ("block_c", cn), ("block_h", H_O)),
+        macs=t.macs,
+        loads=t.main_loads,
+        stores=t.main_stores,
+        vmem_bytes=vmem(nb, cn),
+        machine=machine.name,
+        algorithm="rows",
+        critical_path_steps=ccr.grid_steps(grid),
+    )
+
+
+def _plan_rows_bwd(op: str, machine: MachineModel, kind: str, *, S: int,
+                   pool: int | None, **shape) -> Schedule:
+    """A backward pass of the rows family: it reads the int8 mask the rows
+    forward stored, so it runs only behind that forward (``pool=`` given,
+    the full-rate extents in ``H_O``/``W_O``)."""
+    if not pool or not rows_applies(machine, S):
+        raise ValueError(f"rows {kind} runs behind the rows forward: it needs "
+                         "its pool mask (pool=) and stride 1 on a machine "
+                         "with lanes")
+    return plan_rows(op, machine, kind, pool=pool, **shape)
+
+
 @dataclasses.dataclass(frozen=True)
 class ConvPlanner(ShardablePlanner):
     """The two-level conv argmin: ``algorithm x blocking``.
@@ -261,12 +346,22 @@ class ConvPlanner(ShardablePlanner):
       ``ccr.conv_im2col_traffic`` (the F*F/S^2 patch read amplification,
       charged per strip).
 
-    The fitting schedule with the fewest modeled words wins, ties toward
-    direct.  ``algorithm=`` pins one family the way ``block_*`` pins pin a
-    blocking; a direct-family pin (``block_do``/``block_di``) or a
-    GEMM-family pin (``block_m``/``block_n``/``block_k``) implies its
-    family, so autotune-cached blocks replay into the algorithm that
-    produced them.
+    * **rows** — the row-GEMM family (kernels/conv2d/rows.py) for stride
+      1 on a machine with lanes: F GEMMs of the (W * d_in)-in-lanes input
+      rows against banded filter matrices (``ccr.conv_rows_traffic``,
+      the band's zero MACs included).
+
+    Of direct and im2col, the fitting schedule with the fewest modeled
+    words wins, ties toward direct.  The rows family replaces that winner
+    where it fits and its modeled time (words or MXU passes, whichever
+    is longer) is shorter than the winner's with the winner's lane
+    padding counted: on narrow inputs, whose channels the other families
+    pad to a lane.
+    ``algorithm=`` pins one family the way ``block_*`` pins pin a
+    blocking; a direct-family pin (``block_do``/``block_di``), a
+    GEMM-family pin (``block_m``/``block_n``/``block_k``) or a rows pin
+    (``block_b``/``block_c``) implies its family, so autotune-cached
+    blocks replay into the algorithm that produced them.
 
     On a mesh the forward conv shards as pure data parallelism: "batch"
     (each device convolves batch/P images) or "stack" (each device owns
@@ -349,32 +444,44 @@ class ConvPlanner(ShardablePlanner):
         block_h: int | None = None, block_do: int | None = None,
         algorithm: str | None = None, block_m: int | None = None,
         block_n: int | None = None, block_k: int | None = None,
+        block_b: int | None = None, block_c: int | None = None,
     ) -> Schedule:
         """The two-level argmin: each family's best blocking, then the
-        fitting family with fewer modeled words (ties toward direct)."""
-        if algorithm not in (None, "direct", "im2col"):
+        fitting family with fewer modeled words (ties toward direct),
+        then the rows family where its modeled time is shorter."""
+        if algorithm not in (None, "direct", "im2col", "rows"):
             raise ValueError(f"unknown conv algorithm {algorithm!r}; "
-                             "expected 'direct' or 'im2col'")
-        direct_pins = block_do is not None or block_di is not None
-        gemm_pins = (block_m is not None or block_n is not None
-                     or block_k is not None)
-        if direct_pins and gemm_pins:
+                             "expected 'direct', 'im2col' or 'rows'")
+        pins = {"direct": block_do is not None or block_di is not None,
+                "im2col": (block_m is not None or block_n is not None
+                           or block_k is not None),
+                "rows": block_b is not None or block_c is not None}
+        pinned = [a for a, p in pins.items() if p]
+        if len(pinned) > 1:
             raise ValueError(
-                "block_do/block_di pin the direct kernel and "
-                "block_m/block_n/block_k pin the im2col GEMM — they cannot "
-                "be combined in one conv plan")
-        if algorithm is None:  # a family-specific pin implies its family
-            if direct_pins:
-                algorithm = "direct"
-            elif gemm_pins:
-                algorithm = "im2col"
-        if algorithm == "direct" and gemm_pins:
-            raise ValueError("direct conv has no block_m/block_n/block_k")
-        if algorithm == "im2col" and direct_pins:
-            raise ValueError("im2col conv has no block_do/block_di")
+                "block_do/block_di pin the direct kernel, "
+                "block_m/block_n/block_k the im2col GEMM and "
+                "block_b/block_c the row GEMMs — they cannot be combined "
+                "in one conv plan")
+        if algorithm is None and pinned:  # a pin implies its family
+            algorithm = pinned[0]
+        if pinned and algorithm != pinned[0]:
+            names = {"direct": "block_do/block_di", "rows": "block_b/block_c",
+                     "im2col": "block_m/block_n/block_k"}
+            raise ValueError(
+                f"{algorithm} conv has no {names[pinned[0]]}")
+        rows_ok = rows_applies(self.machine, S)
+        if algorithm == "rows" and not rows_ok:
+            raise ValueError("rows conv needs stride 1 on a machine with "
+                             f"lanes; got S={S} on {self.machine.name!r}")
         shape = dict(H_O=H_O, W_O=W_O, F=F, S=S, d_in=d_in, d_out=d_out,
                      in_bytes=in_bytes, pool=pool, batch=batch,
                      padding=padding, H_I=H_I, W_I=W_I, block_h=block_h)
+        rows_shape = dict(H_O=H_O, W_O=W_O, F=F, d_in=d_in, d_out=d_out,
+                          in_bytes=in_bytes, pool=pool, batch=batch,
+                          block_b=block_b, block_c=block_c)
+        if algorithm == "rows":
+            return plan_rows(self.op, self.machine, "fwd", **rows_shape)
         if algorithm == "im2col":
             return self._plan_im2col(**shape, block_m=block_m,
                                      block_n=block_n, block_k=block_k)
@@ -384,11 +491,34 @@ class ConvPlanner(ShardablePlanner):
             return direct
         im2col = self._plan_im2col(**shape, block_m=block_m,
                                    block_n=block_n, block_k=block_k)
+        best = direct
         if im2col.fits(self.machine) and (
                 im2col.modeled_words < direct.modeled_words
                 or not direct.fits(self.machine)):
-            return im2col
-        return direct
+            best = im2col
+        if rows_ok:
+            rows = plan_rows(self.op, self.machine, "fwd", **rows_shape)
+            fits = all(plan_rows(self.op, self.machine, k, **rows_shape)
+                       .fits(self.machine) for k in ("wgrad", "dgrad"))
+            if fits and rows.fits(self.machine) and self._seconds(
+                    rows, in_bytes) < self._seconds(best, in_bytes, shape):
+                return rows
+        return best
+
+    def _seconds(self, s: Schedule, in_bytes: int, shape=None) -> float:
+        """A schedule's modeled time: its words over the memory bandwidth
+        or its MXU passes over the peak, whichever is longer.  A direct
+        schedule's passes run over its lane-padded channel blocks; the
+        im2col GEMM and the rows family count theirs in ``macs``."""
+        macs = s.macs
+        if s.algorithm == "direct":
+            bdi, bdo = s.block("block_di"), s.block("block_do")
+            macs = (shape["batch"] * shape["H_O"] * shape["W_O"]
+                    * shape["F"] ** 2 * round_up(shape["d_in"], bdi)
+                    * round_up(shape["d_out"], bdo))
+        m = self.machine
+        return max(s.modeled_words * in_bytes / m.main_mem_bw,
+                   2.0 * macs / m.peak_flops)
 
     def _plan_direct(
         self, *, H_O: int, W_O: int, F: int, S: int = 1, d_in: int, d_out: int,
@@ -546,9 +676,18 @@ class ConvPlanner(ShardablePlanner):
             elif any(shape.get(b) is not None
                      for b in ("block_m", "block_n", "block_k")):
                 alg = "im2col"
-        algs = ("direct", "im2col") if alg is None else (alg,)
+            elif any(shape.get(b) is not None for b in ("block_b", "block_c")):
+                alg = "rows"
         pool = shape.get("pool") or 1
         out, seen = [], set()
+        if alg == "rows" or (alg is None and rows_applies(
+                self.machine, shape.get("S", 1))):
+            # The rows family has no strip ladder: one block is whole planes.
+            s = self.plan_local(**{**shape, "algorithm": "rows"})
+            if s.fits(self.machine):
+                out.append(s)
+                seen.add((s.algorithm, s.blocks))
+        algs = {None: ("direct", "im2col"), "rows": ()}.get(alg, (alg,))
         for hb in _strip_ladder(shape["H_O"], pool):
             for a in algs:
                 s = self.plan_local(**{**shape, "block_h": hb,
@@ -628,13 +767,19 @@ class ConvDgradPlanner(ShardablePlanner):
         H_I: int | None = None, W_I: int | None = None,
         block_h: int | None = None, block_do: int | None = None,
         block_di: int | None = None, pool: int | None = None,
-        algorithm: str | None = None,
+        algorithm: str | None = None, block_b: int | None = None,
+        block_c: int | None = None,
     ) -> Schedule:
         if P > F - 1:
             raise ValueError(f"dgrad needs padding <= F-1, got P={P} for F={F}")
-        if algorithm not in (None, "direct", "fused_epilogue"):
+        if algorithm not in (None, "direct", "fused_epilogue", "rows"):
             raise ValueError(f"unknown dgrad algorithm {algorithm!r}; "
-                             "expected 'direct' or 'fused_epilogue'")
+                             "expected 'direct', 'fused_epilogue' or 'rows'")
+        if algorithm == "rows":
+            return _plan_rows_bwd(
+                self.op, self.machine, "dgrad", H_O=H_O, W_O=W_O, F=F, S=S,
+                d_in=d_in, d_out=d_out, in_bytes=in_bytes, pool=pool,
+                batch=batch, block_b=block_b, block_c=block_c)
         if algorithm == "fused_epilogue" and not pool:
             raise ValueError("fused_epilogue dgrad needs the forward pool "
                              "factor (pool=)")
@@ -812,11 +957,17 @@ class ConvWgradPlanner(ShardablePlanner):
         padding: int | None = None, H_I: int | None = None,
         W_I: int | None = None, block_h: int | None = None,
         block_do: int | None = None, block_di: int | None = None,
-        algorithm: str | None = None,
+        algorithm: str | None = None, pool: int | None = None,
+        block_b: int | None = None, block_c: int | None = None,
     ) -> Schedule:
-        if algorithm not in (None, "direct", "pipelined"):
+        if algorithm not in (None, "direct", "pipelined", "rows"):
             raise ValueError(f"unknown wgrad algorithm {algorithm!r}; "
-                             "expected 'direct' or 'pipelined'")
+                             "expected 'direct', 'pipelined' or 'rows'")
+        if algorithm == "rows":
+            return _plan_rows_bwd(
+                self.op, self.machine, "wgrad", H_O=H_O, W_O=W_O, F=F, S=S,
+                d_in=d_in, d_out=d_out, in_bytes=in_bytes, pool=pool,
+                batch=batch, block_b=block_b, block_c=block_c)
         m = self.machine
         lane = m.lane
         P = 0 if padding is None else padding

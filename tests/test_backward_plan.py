@@ -653,7 +653,9 @@ class TestCriticalPathSteps:
 
     @pytest.mark.parametrize("pool", [None, 2])
     def test_conv_bwd_schedules_match_walker(self, pool):
-        bwd = conv_plan_bwd((4, 12, 12, 8), (3, 3, 8, 16), stride=1,
+        # Channels wide enough that the forward plans the direct family
+        # (narrower ones plan the rows family, whose backward is its own).
+        bwd = conv_plan_bwd((4, 12, 12, 64), (3, 3, 64, 128), stride=1,
                             padding=1, pool=pool)
         if pool == 2:
             assert bwd["dgrad"].algorithm == "fused_epilogue"
@@ -661,7 +663,8 @@ class TestCriticalPathSteps:
         else:
             assert "recompute" in bwd
         for sched in bwd.values():
-            self._pin_conv(sched, H_I=12, H_O=12, d_in=8, d_out=16, batch=4)
+            self._pin_conv(sched, H_I=12, H_O=12, d_in=64, d_out=128,
+                           batch=4)
 
     def test_conv_bwd_candidates_cover_both_variants(self):
         """The autotuner's search space carries *both* execution variants

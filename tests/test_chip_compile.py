@@ -330,3 +330,55 @@ def test_lfm2_gemm_forward_and_backward_compile(topo, one_chip, no_cache,
             matmul_dw(x, g, schedule=s_dw, out_dtype=jnp.float32,
                       interpret=False))
     assert "tpu_custom_call" in _compile(bwd, g, w, x)
+
+
+ROWS_BATCH = 6144  # vgg11-b6144's batch
+
+
+@pytest.fixture(scope="module")
+def rows_plan():
+    from repro.configs.registry import get_config
+    from repro.models import cnn
+
+    return cnn.plan_training(get_config("cnn-vgg11"), ROWS_BATCH, in_bytes=2)
+
+
+@pytest.mark.parametrize("direction", ("fwd", "wgrad", "dgrad"))
+def test_conv0_rows_kernels_compile_and_fit(topo, one_chip, no_cache,
+                                            rows_plan, direction):
+    """conv0 at vgg11-b6144's shapes runs the row-GEMM family: each of its
+    kernels lowers to Mosaic, and each planned working set fits VMEM."""
+    from repro.core.machine import TPU_V5E
+    from repro.kernels.conv2d import rows
+
+    s = rows_plan["conv0" if direction == "fwd" else f"conv0.{direction}"]
+    assert s.algorithm == "rows" and s.fits(TPU_V5E), (s, s.vmem_bytes)
+    geo = rows.rows_geometry((ROWS_BATCH, 32, 32, 3), (3, 3, 3, 64),
+                             padding=1, pool=2, dtype=jnp.bfloat16,
+                             block_b=s.block("block_b"))
+    K, Nh, V = geo.K, geo.Nh, geo.V
+    kw = dict(geo=geo, interpret=False, **rows._blocking(s, geo))
+
+    def spec(shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    x4 = spec((V, geo.Bp * geo.Hq, K))
+    y2, m2 = spec((geo.Bp * geo.Hq, Nh)), spec((geo.Bp * geo.Hq, Nh), jnp.int8)
+    t = spec((3, 2, K, Nh))
+    if direction == "fwd":
+        def fn(x4, t, b):
+            return rows._conv_rows_impl(x4, t, b, relu=True, emit_mask=True,
+                                        out_dtype=jnp.bfloat16, **kw)
+
+        args = x4, t, spec((1, Nh), jnp.float32)
+    elif direction == "wgrad":
+        def fn(x4, g, m):
+            return rows._conv_rows_wgrad_impl(x4, g, m, **kw)
+
+        args = x4, y2, m2
+    else:
+        def fn(g, m, t):
+            return rows._conv_rows_dgrad_impl(g, m, t, **kw)
+
+        args = y2, m2, t
+    assert "tpu_custom_call" in _compile(fn, *args)

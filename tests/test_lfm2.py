@@ -227,10 +227,12 @@ def test_attention_backward_block_holds_no_full_score_matrix():
 # The cnn-vgg11 cells' planned schedules (op, algorithm, grid, blocks),
 # as they were before the fused dX/dW kernel's VMEM count took its output
 # blocks in: the corrected count leaves both cells' plans as they were.
+# conv0's 3 input channels run the row-GEMM family in both cells; every
+# other stage keeps its schedule.
 VGG11_B256 = {
-    "conv0": ("conv2d", "direct", (256, 1, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 32))),
-    "conv0.dgrad": ("conv2d_dgrad", "fused_epilogue", (256, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 32))),
-    "conv0.wgrad": ("conv2d_wgrad", "pipelined", (1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 32))),
+    "conv0": ("conv2d", "rows", (8,), (("block_b", 32), ("block_c", 256), ("block_h", 32))),
+    "conv0.dgrad": ("conv2d_dgrad", "rows", (8,), (("block_b", 32), ("block_c", 256), ("block_h", 32))),
+    "conv0.wgrad": ("conv2d_wgrad", "rows", (8,), (("block_b", 32), ("block_c", 256), ("block_h", 32))),
     "conv1": ("conv2d", "direct", (256, 1, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 16))),
     "conv1.dgrad": ("conv2d_dgrad", "fused_epilogue", (256, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 16))),
     "conv1.wgrad": ("conv2d_wgrad", "pipelined", (1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 16))),
@@ -248,9 +250,9 @@ VGG11_B256 = {
     "fc2.dx": ("matmul_dx", "fused_dxdw", (2, 2, 1), (("block_k", 2048), ("block_m", 256), ("block_n", 512))),
 }
 VGG11_B6144 = {
-    "conv0": ("conv2d", "direct", (6144, 1, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 32))),
-    "conv0.dgrad": ("conv2d_dgrad", "fused_epilogue", (6144, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 32))),
-    "conv0.wgrad": ("conv2d_wgrad", "pipelined", (1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 32))),
+    "conv0": ("conv2d", "rows", (192,), (("block_b", 32), ("block_c", 256), ("block_h", 32))),
+    "conv0.dgrad": ("conv2d_dgrad", "rows", (192,), (("block_b", 32), ("block_c", 256), ("block_h", 32))),
+    "conv0.wgrad": ("conv2d_wgrad", "rows", (192,), (("block_b", 32), ("block_c", 256), ("block_h", 32))),
     "conv1": ("conv2d", "direct", (6144, 1, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 16))),
     "conv1.dgrad": ("conv2d_dgrad", "fused_epilogue", (6144, 1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 16))),
     "conv1.wgrad": ("conv2d_wgrad", "pipelined", (1, 1), (("block_di", 128), ("block_do", 128), ("block_h", 16))),

@@ -218,9 +218,10 @@ class TestScheduleProperties:
         sched = ConvPlanner(TPU_V5E).plan(
             H_O=H_O, W_O=H_O, F=F, S=S, d_in=di, d_out=do, in_bytes=ib
         )
-        # The two-level argmin may pick either family; each family's
+        # The two-level argmin may pick any family; each family's
         # output-channel block is lane-aligned.
-        out_block = "block_n" if sched.algorithm == "im2col" else "block_do"
+        out_block = {"im2col": "block_n", "rows": "block_c"}.get(
+            sched.algorithm, "block_do")
         assert sched.block(out_block) % TPU_V5E.lane == 0
         assert 0 < sched.block("block_h") <= H_O + 1
         assert sched.modeled_words == sched.loads + sched.stores
